@@ -16,6 +16,8 @@ import time
 def main() -> None:
     from benchmarks import ablation, loc_table, overhead, report, \
         sensitivity, throughput_model
+    from repro.launch.jax_cache import use_compile_cache
+    use_compile_cache()
     for mod in (loc_table, overhead, throughput_model, ablation,
                 sensitivity, report):
         name = mod.__name__.split(".")[-1]

@@ -5,8 +5,4 @@ name, or raw traced Module) to a ``Program`` whose step builders route
 through the plan IR, the persistent PlanStore and the tiered serve
 runtime.  ``repro.core`` holds the substrate those builders compose.
 """
-from ._compat import install_jax_shims
-
-install_jax_shims()
-
-from . import api  # noqa: E402,F401  (the facade is the public frontend)
+from . import api  # noqa: F401  (the facade is the public frontend)

@@ -117,6 +117,7 @@ class LoweredPlan:
         import jax
         import jax.tree_util as jtu
         from jax.api_util import shaped_abstractify
+        from jax.extend.core import jaxpr_as_fun
         flat, treedef = jtu.tree_flatten((params, inputs))
         try:
             avals = tuple(shaped_abstractify(x) for x in flat)
@@ -135,7 +136,7 @@ class LoweredPlan:
             # memoizes pjit tracing on (function, avals), so every later
             # re-trace of this capture binds one cached call instead of
             # re-running op-level Python
-            stable = jax.jit(jax.core.jaxpr_as_fun(closed))
+            stable = jax.jit(jaxpr_as_fun(closed))
             hit = (closed, jtu.tree_structure(shape), stable)
             self._replays[key] = hit
             self.stats["captures"] = self.stats.get("captures", 0) + 1

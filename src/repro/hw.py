@@ -1,18 +1,68 @@
-"""Hardware model constants for the roofline target (TPU v5e-class chip).
+"""Hardware table, keyed by ``jax.Device.device_kind``.
 
-The container is CPU-only; these constants parameterize the roofline
-analysis of the compiled (dry-run) artifacts, per the assignment:
-  197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peaks come from the Google Cloud documentation page "TPU v5e" (system
+architecture): per chip 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at
+819 GB/s, and 1,600 Gbit/s of inter-chip interconnect.  ``vmem_bytes`` is
+the scoped VMEM a Pallas kernel may use without raising the compiler's
+limit (16 MiB on v5e, as the TPU compiler reports when a kernel exceeds
+it), not the chip's physical 128 MiB.
+
+``chip(kind)`` raises for a kind that is not in the table: numbers for one
+chip are never silently applied to another.  The module-level constants
+are the repo's modelling target (v5e), read by the roofline cost models.
 """
+from __future__ import annotations
 
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW_PER_LINK = 50e9    # bytes/s per link
-ICI_LINKS_PER_CHIP = 4    # 2D torus within a pod: +x,-x,+y,-y (v5e-256 is a 16x16 torus)
-COLL_LATENCY_S = 20e-6    # collective launch latency: ring setup + per-hop
-VMEM_BYTES = 128 * 1024 * 1024  # ~128 MiB VMEM per chip (v5e class)
-MXU_TILE = 128            # systolic array native tile edge
-HBM_BYTES = 16e9          # 16 GiB HBM per v5e chip
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class Chip:
+    name: str
+    peak_flops_bf16: float    # FLOP/s
+    peak_ops_int8: float      # OP/s
+    hbm_bytes: float
+    hbm_bw: float             # bytes/s
+    ici_bw: float             # bytes/s per chip, all links together
+    ici_links: int            # 2D torus: +x, -x, +y, -y
+    vmem_bytes: int           # default scoped VMEM limit of one kernel
+    mxu_tile: int             # systolic array native tile edge
+    source: str
+
+
+_V5E = Chip(
+    name="TPU v5e", peak_flops_bf16=197e12, peak_ops_int8=393e12,
+    hbm_bytes=16e9, hbm_bw=819e9, ici_bw=1600e9 / 8, ici_links=4,
+    vmem_bytes=16 * 1024 * 1024, mxu_tile=128,
+    source='Google Cloud documentation, "TPU v5e"')
+
+# jax reports a v5e chip as "TPU v5 lite"
+CHIPS = {"TPU v5 lite": _V5E}
+
+
+class UnknownDevice(KeyError):
+    """A ``device_kind`` with no entry in :data:`CHIPS`."""
+
+
+def chip(kind: str) -> Chip:
+    try:
+        return CHIPS[kind]
+    except KeyError:
+        raise UnknownDevice(
+            f"no hardware entry for device kind {kind!r}; known: "
+            f"{sorted(CHIPS)}") from None
+
+
+TARGET = _V5E
+
+PEAK_FLOPS_BF16 = TARGET.peak_flops_bf16
+HBM_BW = TARGET.hbm_bw
+ICI_LINKS_PER_CHIP = TARGET.ici_links
+ICI_BW_PER_LINK = TARGET.ici_bw / TARGET.ici_links
+COLL_LATENCY_S = 20e-6    # collective launch latency: modelled, not measured
+VMEM_BYTES = TARGET.vmem_bytes
+MXU_TILE = TARGET.mxu_tile
+HBM_BYTES = TARGET.hbm_bytes
 
 DTYPE_BYTES = {
     "float32": 4, "f32": 4,

@@ -38,10 +38,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal, sm_scale,
 
     def body(kb, carry):
         acc, m, lsum = carry
-        k = pl.load(k_ref, (pl.ds(0, 1),
-                            pl.ds(kb * block_k, block_k), slice(None)))[0]
-        v = pl.load(v_ref, (pl.ds(0, 1),
-                            pl.ds(kb * block_k, block_k), slice(None)))[0]
+        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
+        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T                     # (bq, bk)
         if causal:
             qpos = qi * block_q + lax.broadcasted_iota(

@@ -31,8 +31,7 @@ from ..dist import collectives as col
 
 
 def fused_ar_add_rmsnorm(y_partial, x, g, *, axis: str = "model",
-                         eps: float = 1e-5, block_rows: int = 256,
-                         interpret: bool = True):
+                         eps: float = 1e-5, block_rows: int = 256):
     """Fused psum(y) + (x + .) + rmsnorm over mesh axis ``axis``.
 
     y_partial, x: (B, S, d) with S divisible by the axis size.

@@ -28,7 +28,7 @@ def mesh_shape_dict(mesh) -> dict:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def make_mesh_info(mesh, *, fsdp: bool = False, attn_impl: str = "chunked",
+def make_mesh_info(mesh, *, fsdp: bool = False, attn_impl: str = "xla",
                    fsdp_resident: bool = False):
     from ..models.layers import MeshInfo
     d = mesh_shape_dict(mesh)

@@ -7,19 +7,26 @@ model, built through the ``repro.api`` facade.
 ``--baseline`` reverts the engine to the synchronous fixed-batch shape
 (single decode tier, one-request prefill, per-step host sync) for A/B
 comparison against the tiered async default.
+
+Exits 1 when any request ends without finishing (``Failed`` or ``Shed``):
+the engine turns dispatch errors into per-request failures, so a clean
+exit must mean every request was served.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import numpy as np
 
 from .. import api
 from ..serve import Request, ServeConfig
+from .jax_cache import use_compile_cache
 
 
-def main(argv=None):
+def serve(argv=None) -> list:
+    """Run the launcher; returns every terminated request."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="chatglm3-6b")
     ap.add_argument("--smoke", action="store_true")
@@ -42,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--plan-store", default=None,
                     help="persist lowered plans here (warm restarts)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     program = api.compile(args.arch, policy=args.strategy,
                           smoke=args.smoke,
@@ -74,13 +82,24 @@ def main(argv=None):
           f"({st['host_syncs']} host syncs / {st['decode_steps']} decode "
           f"steps, {st['row_moves']} row moves, "
           f"{st['chunk_steps']} chunk steps)")
-    ttfts = [r.first_token_s - r.submitted_s for r in done]
-    print(f"TTFT p50={np.percentile(ttfts, 50)*1e3:.0f}ms "
-          f"p99={np.percentile(ttfts, 99)*1e3:.0f}ms")
+    ttfts = [r.first_token_s - r.submitted_s for r in done
+             if r.first_token_s]
+    if ttfts:
+        print(f"TTFT p50={np.percentile(ttfts, 50)*1e3:.0f}ms "
+              f"p99={np.percentile(ttfts, 99)*1e3:.0f}ms "
+              f"over {len(ttfts)} requests with a first token")
+    bad = [r for r in done if not r.ok]
+    for r in bad:
+        print(f"request {r.rid} ended {r.result}", file=sys.stderr)
     eng.shutdown()
     program.close()
     return done
 
 
+def main(argv=None) -> int:
+    """Exit code: 0 when every request finished, else 1."""
+    return 0 if all(r.ok for r in serve(argv)) else 1
+
+
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -105,3 +105,42 @@ def shard_specs_of(shardings):
     return jax.tree_util.tree_map(
         lambda s: s.spec, shardings,
         is_leaf=lambda x: isinstance(x, NamedSharding))
+
+
+def dense_tp_params(params, model):
+    """Single-chip (tp=1) params of a dense LM, laid out as the global
+    params of ``model``, the same architecture built for ``model.mesh.tp``.
+
+    Two leaves change layout between the two: the fused QKV projection,
+    whose columns regroup per shard (the shard's q heads, then the k and
+    v heads it stores, copied onto several shards when tp > n_kv), and
+    the fused SwiGLU input ``[w1 | w3]``, which interleaves per shard.
+    Every other leaf is shared as is.  Works on numpy arrays, so the
+    relayout can run on the host."""
+    import numpy as np
+    lay, cfg = model.layout, model.cfg
+    if lay.q_pad != lay.n_q:
+        raise ValueError(f"{cfg.name}: {lay.n_q} q heads do not split "
+                         f"evenly over tp={lay.tp}")
+    hd = lay.head_dim
+
+    def heads(base, ids):
+        return [base + h * hd + j for h in ids for j in range(hd)]
+
+    qkv_cols, mlp_cols = [], []
+    ff = cfg.d_ff // lay.tp
+    for s, kv_ids in enumerate(lay.kv_store_map()):
+        q_ids = range(s * lay.q_local, (s + 1) * lay.q_local)
+        qkv_cols += heads(0, q_ids)
+        qkv_cols += heads(lay.n_q * hd, kv_ids)
+        qkv_cols += heads((lay.n_q + lay.n_kv) * hd, kv_ids)
+        mlp_cols += list(range(s * ff, (s + 1) * ff))
+        if cfg.act == "swiglu":                   # w3 follows w1
+            mlp_cols += [cfg.d_ff + c for c in range(s * ff, (s + 1) * ff)]
+    layers = dict(params["layers"])
+    qkv = layers["qkv"]["proj"]["lin"]["w"]
+    wi = layers["mlp"]["wi"]["lin"]["w"]
+    layers["qkv"] = {"proj": {"lin": {"w": np.take(qkv, qkv_cols, -1)}}}
+    layers["mlp"] = dict(layers["mlp"],
+                         wi={"lin": {"w": np.take(wi, mlp_cols, -1)}})
+    return dict(params, layers=layers)

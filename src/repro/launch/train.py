@@ -21,6 +21,7 @@ from ..data import DataConfig, SyntheticBackend, TokenPipeline
 from ..ft.elastic import FailureSimulator
 from ..optim import AdamWConfig
 from ..train import TrainLoopConfig, TrainStepConfig, train_loop
+from .jax_cache import use_compile_cache
 
 
 def main(argv=None):
@@ -41,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--crash-at", type=int, default=-1,
                     help="inject a simulated failure at this step")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     program = api.compile(args.arch, policy=args.strategy,
                           smoke=args.smoke)

@@ -15,6 +15,7 @@ Conventions
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -435,9 +436,17 @@ class LMBase:
         return {}
 
     # shared ------------------------------------------------------------------
+    def uses_sp(self, phase: str) -> bool:
+        """Sequence parallelism for this phase's train/prefill sections.
+
+        Off for decode, and off at tp=1: there the reduce-scatter /
+        all-gather pair is an identity, so the layer keeps its all-reduce
+        form, whose [all-reduce -> add -> RMSNorm] chains TokenWeave fuses."""
+        return (bool(self.cfg.seq_parallel) and self.mesh.tp > 1
+                and phase != "decode")
+
     def seq_local(self, phase: str, S: int) -> int:
-        sp = self.cfg.seq_parallel and phase != "decode"
-        return S // self.mesh.tp if sp else S
+        return S // self.mesh.tp if self.uses_sp(phase) else S
 
     def build_segments(self, phase: str, B_loc: int, S: int,
                        s_max: int = 0) -> tuple[list[Segment], dict]:
@@ -577,10 +586,14 @@ class LMBase:
                 if p:
                     out[seg.name] = p
             else:
-                ks = [jax.random.fold_in(k, i) for i in range(seg.count)]
-                ps = [seg.module.init(kk, global_=global_) for kk in ks]
-                out[seg.name] = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *ps)
+                # one jitted map writes each layer into the stacked
+                # buffer: peak memory is the stack plus one layer, never
+                # a list of layers and its stacked copy at once
+                ks = jnp.stack([jax.random.fold_in(k, i)
+                                for i in range(seg.count)])
+                init = functools.partial(seg.module.init, global_=global_)
+                out[seg.name] = jax.jit(
+                    functools.partial(lax.map, init))(ks)
         return out
 
     def param_shapes(self, segs, global_=True) -> dict:

@@ -553,8 +553,13 @@ class MoELM(LMBase):
         super().__init__(cfg, mesh)
         self.layout = HeadLayout(cfg.n_heads, cfg.n_kv, mesh.tp, cfg.hd)
 
+    def uses_sp(self, phase):
+        # the token-sharded MoE block's all-to-alls are what DBO and Comet
+        # schedule around, so keep that form even at tp=1
+        return bool(self.cfg.seq_parallel) and phase != "decode"
+
     def make_embed(self, phase):
-        sp = self.cfg.seq_parallel and phase != "decode"
+        sp = self.uses_sp(phase)
         return EmbedSegment(self.cfg, self.mesh, sp)
 
     def layer_stacks(self, phase):
@@ -572,7 +577,7 @@ class MoELM(LMBase):
                                {"input_map": dict(cmap),
                                 "output_map": dict(cmap)}))
             else:
-                dmod = DenseDecoderLayer(cfg, mesh, cfg.seq_parallel,
+                dmod = DenseDecoderLayer(cfg, mesh, self.uses_sp(phase),
                                          collect_kv=(phase == "prefill"))
                 omap = ({"k": "dense0.k", "v": "dense0.v"}
                         if phase == "prefill" else {})
@@ -584,14 +589,14 @@ class MoELM(LMBase):
             stacks.append(("layers", mod, n_moe,
                            ("k_cache", "v_cache"), ("k_cache", "v_cache")))
         else:
-            mod = MoEDecoderLayer(cfg, mesh, cfg.seq_parallel,
+            mod = MoEDecoderLayer(cfg, mesh, self.uses_sp(phase),
                                   collect_kv=(phase == "prefill"))
             stacks.append(("layers", mod, n_moe, (),
                            ("k", "v") if phase == "prefill" else ()))
         return stacks
 
     def make_head(self, phase):
-        sp = self.cfg.seq_parallel and phase != "decode"
+        sp = self.uses_sp(phase)
         if phase == "train":
             return TrainHead(self.cfg, self.mesh, sp)
         return LogitsHead(self.cfg, self.mesh, sp,

@@ -19,7 +19,7 @@ class DenseLM(LMBase):
         self.layout = HeadLayout(cfg.n_heads, cfg.n_kv, mesh.tp, cfg.hd)
 
     def make_embed(self, phase):
-        sp = self.cfg.seq_parallel and phase != "decode"
+        sp = self.uses_sp(phase)
         return EmbedSegment(self.cfg, self.mesh, sp)
 
     def layer_stacks(self, phase):
@@ -28,13 +28,13 @@ class DenseLM(LMBase):
             mod = DenseDecodeLayer(cfg, mesh)
             return [("layers", mod, cfg.n_layers,
                      ("k_cache", "v_cache"), ("k_cache", "v_cache"))]
-        sp = cfg.seq_parallel
-        mod = DenseDecoderLayer(cfg, mesh, sp, collect_kv=(phase == "prefill"))
+        mod = DenseDecoderLayer(cfg, mesh, self.uses_sp(phase),
+                                collect_kv=(phase == "prefill"))
         sc_out = ("k", "v") if phase == "prefill" else ()
         return [("layers", mod, cfg.n_layers, (), sc_out)]
 
     def make_head(self, phase):
-        sp = self.cfg.seq_parallel and phase != "decode"
+        sp = self.uses_sp(phase)
         if phase == "train":
             return TrainHead(self.cfg, self.mesh, sp)
         return LogitsHead(self.cfg, self.mesh, sp,

@@ -33,7 +33,7 @@ class VLM(DenseLM):
     family = "vlm"
 
     def make_embed(self, phase):
-        sp = self.cfg.seq_parallel and phase != "decode"
+        sp = self.uses_sp(phase)
         if phase == "decode":
             return EmbedSegment(self.cfg, self.mesh, sp)
         return VLMEmbedSegment(self.cfg, self.mesh, sp)

@@ -151,7 +151,7 @@ def _build_train_step(model, scheduler, B_loc: int, S: int,
                         verify=verify, verify_sink=verify_sink)
     checkpoint_plan_store(plan_store)
     pspecs = model.param_pspecs(segs)
-    sp_train = bool(getattr(model.cfg, "seq_parallel", False))
+    sp_train = model.uses_sp("train")
     mesh_info = model.mesh
 
     def loss_fn(params, batch):

@@ -1,5 +1,4 @@
 import os
-import sys
 
 # Smoke tests and benches must see ONE device; only dryrun.py forces 512.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -7,14 +6,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import numpy as np
 import pytest
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    # container doesn't ship hypothesis — install the deterministic stub
-    from repro._compat import hypothesis_stub as _hyp
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _hyp.strategies
 
 
 @pytest.fixture(scope="session")

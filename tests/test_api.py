@@ -449,3 +449,22 @@ def test_program_bundle_opaque_policy(tmp_path):
     p2 = repro.api.Program.load(path, policy="sequential")
     p2.prefill(global_batch=1, seq_len=16)
     assert p2.stats["misses"] == 0
+
+
+def test_stacked_init_matches_per_layer_init():
+    """Layer stacks are initialized by one jitted map into the stacked
+    buffer (no list-plus-stack double copy); each layer's slice equals
+    initializing that layer alone."""
+    import zlib
+    program = repro.api.compile("chatglm3-6b", smoke=True)
+    params = program.init_params(0)
+    segs, _ = program.model.build_segments("prefill", 2, 2, s_max=4)
+    seg = next(s for s in segs if s.count > 1)
+    k = jax.random.fold_in(jax.random.PRNGKey(0),
+                           zlib.crc32(seg.name.encode()))
+    for i in range(seg.count):
+        one = seg.module.init(jax.random.fold_in(k, i))
+        got = jax.tree_util.tree_map(lambda x: x[i], params[seg.name])
+        assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+            lambda a, b: bool((np.asarray(a) == np.asarray(b)).all()),
+            one, got))

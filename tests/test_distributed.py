@@ -379,3 +379,17 @@ def test_decode_tier_steps_share_one_lowering_tp2():
         jax.jit(fn).lower(*in_sdss).compile()
         print("decode tiers OK")
     """)
+
+
+def test_chip_smoke_tp_phase_matches_one_device():
+    """``chip_smoke.py --chips 4``'s comparison at smoke size: chatglm3
+    prefill + one decode step on a (1, 4) mesh, with the one-device params
+    relaid out by ``dense_tp_params`` (replicated-KV branch: n_kv=2 < tp),
+    against one device."""
+    out = run_devices(4, """
+        import chip_smoke
+        fails = chip_smoke.tp_phase(0, smoke=True, batch=4, seq=64,
+                                    s_max=128)
+        assert not fails, fails
+    """)
+    assert "tp: decode logits" in out

@@ -45,6 +45,43 @@ def test_fused_add_rmsnorm_sweep(n, d, dtype):
                                np.asarray(h2, np.float32), **tol(dtype))
 
 
+@pytest.mark.parametrize("n,d,block_rows", [(300, 64, 128), (4095, 576, 256),
+                                            (41, 32, 16), (264, 32, 256)])
+def test_fused_add_rmsnorm_padded_rows(n, d, block_rows):
+    """Row counts no aligned block divides are padded, not split into
+    blocks that break the (8, 128) tiling."""
+    from repro.kernels import rmsnorm as rn
+    br, n_pad = rn.row_block(n, d, 4, 4, block_rows)
+    assert br % 8 == 0 and n_pad % br == 0 and n <= n_pad < n + 8
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, d))
+    y = jax.random.normal(jax.random.PRNGKey(1), (n, d))
+    g = jax.random.normal(jax.random.PRNGKey(2), (d,))
+    s1, h1 = rn.fused_add_rmsnorm(x, y, g, block_rows=block_rows)
+    s2, h2 = ref.fused_add_rmsnorm(x, y, g)
+    np.testing.assert_allclose(s1, s2, **tol(jnp.float32))
+    np.testing.assert_allclose(h1, h2, **tol(jnp.float32))
+
+
+def test_row_block_fits_scoped_vmem():
+    from repro import hw
+    from repro.kernels.rmsnorm import row_block
+    # chatglm3-6b width: 256 requested rows would overflow 16 MiB
+    assert row_block(4096, 4096, 2, 4, 256) == (128, 4096)
+    assert row_block(4096, 576, 2, 4, 256) == (256, 4096)
+    assert row_block(264, 576, 2, 4, 256) == (8, 264)   # divides: no pad
+    br, _ = row_block(4096, 8192, 2, 4, 256)
+    assert br * 8192 * (2 * 4 * 2 + 12) <= hw.VMEM_BYTES
+
+
+def test_interpret_mode_follows_backend():
+    assert jax.default_backend() == "cpu" and ops.interpret_mode()
+    before = sum(n for k, n in ops.traced.items() if k[2] == 24)
+    ops.fused_add_rmsnorm(jnp.ones((2, 12, 32)), jnp.ones((2, 12, 32)),
+                          jnp.ones((32,)), 128)
+    assert ops.traced[("fused_add_rmsnorm", True, 24, 32, 128)] >= 1
+    assert sum(n for k, n in ops.traced.items() if k[2] == 24) > before
+
+
 def test_fused_add_rmsnorm_grad_matches_autodiff():
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 32))
     y = jax.random.normal(jax.random.PRNGKey(1), (8, 32))

@@ -144,3 +144,13 @@ def test_wire_bytes_ring_factors():
     w = wire_bytes({"all-reduce": 100, "all-gather": 100,
                     "all-to-all": 100}, axis_size=4)
     assert abs(w - (200 * 0.75 + 100 * 0.75 + 25 * 0.75)) < 1e-9
+
+
+def test_hw_table_keyed_by_device_kind():
+    v5e = hw.chip("TPU v5 lite")
+    assert v5e is hw.TARGET
+    assert v5e.peak_flops_bf16 == 197e12 and v5e.hbm_bw == 819e9
+    assert hw.VMEM_BYTES == 16 * 1024 * 1024
+    assert hw.ICI_LINKS_PER_CHIP * hw.ICI_BW_PER_LINK == 1600e9 / 8
+    with pytest.raises(hw.UnknownDevice):
+        hw.chip("cpu")

@@ -171,3 +171,57 @@ def test_train_step_builder_warm_starts(engine_setup, tmp_path,
     monkeypatch.setattr(plan_store_mod, "lower", bomb)
     build_train_step(model, get_strategy("sequential"), 2, 16, tcfg,
                      plan_store_path=path)
+
+
+def _serve_argv():
+    return ["--arch", "chatglm3-6b", "--smoke", "--requests", "3",
+            "--max-new", "4"]
+
+
+def test_serve_launcher_serves_every_request(tmp_path):
+    """The smoke launcher run finishes every request with tokens: a clean
+    exit must not hide requests the engine failed."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH=os.path.join(repo, "src"))
+    r = subprocess.run([sys.executable, "-m", "repro.launch.serve",
+                        *_serve_argv()], capture_output=True, text=True,
+                       timeout=300, env=env)
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert "served 3 requests, 12 tokens" in r.stdout
+    assert "'failed': 0" in r.stdout and "'shed': 0" in r.stdout
+    assert "TTFT p50=" in r.stdout and "TTFT p50=-" not in r.stdout
+
+
+def test_serve_launcher_exits_nonzero_on_failed_requests(monkeypatch,
+                                                         capsys):
+    from repro.launch import serve as launcher
+
+    def broken(self, bp, bucket):
+        raise RuntimeError("injected prefill build failure")
+
+    monkeypatch.setattr(launcher, "use_compile_cache", lambda: "")
+    monkeypatch.setattr(ServeEngine, "_prefill_fn", broken)
+    assert launcher.main(_serve_argv()) == 1
+    out = capsys.readouterr()
+    assert "TTFT" not in out.out          # no request produced a token
+    assert "injected prefill build failure" in out.err
+
+
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    from repro.launch import jax_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(jax_cache.ENV, str(tmp_path))
+        assert jax_cache.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv(jax_cache.ENV)
+        want = str(jax_cache.CHECKOUT / ".jax_cache")
+        assert jax_cache.use_compile_cache() == want
+        assert (jax_cache.CHECKOUT / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

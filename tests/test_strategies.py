@@ -153,3 +153,23 @@ def test_loc_budget_matches_paper_table2():
         loc = len([l for l in src.splitlines()
                    if l.strip() and not l.strip().startswith(("#", '"'))])
         assert loc <= 80, (cls, loc)
+
+
+def test_dynamic_picks_tokenweave_for_large_dense_prefill_at_tp1():
+    """At tp=1 sequence parallelism is off (its collectives would be
+    identities), so dense layers keep the [all-reduce -> add -> RMSNorm]
+    chain and a >= 2048-token prefill resolves to TokenWeave."""
+    from repro import api
+    from repro.core.policy import resolve_strategy
+    from repro.core.strategies import TokenWeave
+    program = api.compile("chatglm3-6b", smoke=True)
+    segs, _ = program.model.build_segments("prefill", 4, 1024, s_max=1024)
+    layers = next(s for s in segs if s.name == "layers")
+    names = [n.name for n in layers.graph.nodes.values()]
+    assert any("ar_attn" in n for n in names)
+    assert not any("rs_attn" in n or "ag_attn" in n for n in names)
+    info = ScheduleContext(local_batch=4, seq_len=1024, phase="prefill",
+                           arch=program.model.cfg.name)
+    picked = resolve_strategy(program.policy, info, graph=layers.graph)
+    assert isinstance(picked, TokenWeave)
+    assert TokenWeave().triples(layers.graph)
