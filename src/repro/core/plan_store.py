@@ -49,16 +49,23 @@ Entries are LRU-bounded both by count and by an estimated byte budget;
 evictions, hits, misses and shares are all counted in ``stats``.  The
 executable level (``get_or_build``) keeps the old CompileCache contract
 under ``exec_*`` counters, with its own entry-count and byte budgets.
+Host time goes to span counters in ``spans`` (``plan.lower``,
+``plan.specialize``, ``plan.restore``, ``plan.trace``, ``plan.compile``;
+a rejected specialize or restore is timed apart, under
+``plan.specialize_rejected`` or ``plan.restore_rejected``).
+``snapshot()`` also reports the seconds of ``plan.lower`` and
+``plan.specialize`` as ``lower_s`` and ``specialize_s``.
 """
 from __future__ import annotations
 
-import time
+import copy
 from collections import OrderedDict
 from typing import Callable, Optional
 
 import jax
 
 from .._deprecation import warn_once
+from ..spans import span
 from .lowering import LoweredPlan, LoweringError, lower, specialize
 from .plan import FINGERPRINT_VERSION, structural_key
 from .plan_serde import (FORMAT_VERSION, RestoreError, encode_analysis,
@@ -162,19 +169,19 @@ class PlanStore:
         self._dirty = False                        # plan-level state vs disk
         self.stats = {
             "hits": 0, "misses": 0, "shares": 0, "evictions": 0,
-            "specialize_rejects": 0,
-            "lower_s": 0.0, "specialize_s": 0.0, "plan_bytes": 0,
+            "specialize_rejects": 0, "plan_bytes": 0,
             "one_shot_evictions": 0,
             "restore_hits": 0, "restore_canonicals": 0,
             "restore_entries": 0, "restore_rejected": 0,
             "restore_verify_rejected": 0,
             "restore_errors": 0, "restore_saved": 0, "restore_skipped": 0,
-            "restore_s": 0.0,
             "exec_hits": 0, "exec_misses": 0, "exec_evictions": 0,
-            "exec_bytes": 0, "compile_s": 0.0, "trace_s": 0.0,
+            "exec_bytes": 0,
             "verdicts_put": 0, "verdict_hits": 0, "verdict_misses": 0,
             "verdict_rejected": 0,
         }
+        # host time of plan builds: span counters named plan.* (repro.spans)
+        self.spans: dict = {}
 
     # -- plan level --------------------------------------------------------
     def get_or_lower(self, graph, plan, analysis=None, salt: str = "",
@@ -208,18 +215,18 @@ class PlanStore:
             canonical = self._skeleton_canonical(restored, outer, graph,
                                                  plan, skey)
         if canonical is not None:
-            t0 = time.perf_counter()
-            try:
-                lowered = specialize(canonical, graph, plan, capture=capture,
-                                     struct_key=skey)
-            except LoweringError:
-                # structure drifted (e.g. a batch tier whose scheduler
-                # changed the micro-batch count): full lower below,
-                # observable so tier configs that never share are loud
-                lowered = None
-                self.stats["specialize_rejects"] += 1
+            with span(self.spans, "plan.specialize") as sp:
+                try:
+                    lowered = specialize(canonical, graph, plan,
+                                         capture=capture, struct_key=skey)
+                except LoweringError:
+                    # structure drifted (e.g. a batch tier whose scheduler
+                    # changed the micro-batch count): full lower below,
+                    # observable so tier configs that never share are loud
+                    lowered = None
+                    self.stats["specialize_rejects"] += 1
+                    sp.name = "plan.specialize_rejected"
             if lowered is not None:
-                self.stats["specialize_s"] += time.perf_counter() - t0
                 self.stats["shares"] += 1
                 # a specialized plan has the canonical's instr structure,
                 # so its byte estimate is the canonical's — skip the walk
@@ -235,9 +242,8 @@ class PlanStore:
                 self._insert(outer, key, lowered, nbytes)
                 return lowered
         self.stats["misses"] += 1
-        t0 = time.perf_counter()
-        lowered = lower(graph, plan, analysis, capture=capture)
-        self.stats["lower_s"] += time.perf_counter() - t0
+        with span(self.spans, "plan.lower"):
+            lowered = lower(graph, plan, analysis, capture=capture)
         self._insert(outer, key, lowered)
         return lowered
 
@@ -477,16 +483,16 @@ class PlanStore:
         """Exact-bucket restore: rebind callables from the live (graph,
         plan) and admit the result as a live entry — zero ``lower`` and
         zero ``specialize`` cost."""
-        t0 = time.perf_counter()
-        try:
-            lowered = rehydrate(rec, restored["analysis"], graph, plan,
-                                struct_key=skey)
-        except RestoreError:
-            self.stats["restore_rejected"] += 1
-            return None
-        if not self._verify_restored_plan(lowered):
-            return None
-        self.stats["restore_s"] += time.perf_counter() - t0
+        with span(self.spans, "plan.restore") as sp:
+            try:
+                lowered = rehydrate(rec, restored["analysis"], graph, plan,
+                                    struct_key=skey)
+            except RestoreError:
+                lowered = None
+                self.stats["restore_rejected"] += 1
+            if lowered is None or not self._verify_restored_plan(lowered):
+                sp.name = "plan.restore_rejected"
+                return None
         self.stats["restore_hits"] += 1
         self._insert(outer, key, lowered)
         # a cross-generation reuse is by definition not one-shot
@@ -566,14 +572,12 @@ class PlanStore:
             self._execs.move_to_end(key)
             return hit[0]
         self.stats["exec_misses"] += 1
-        t0 = time.perf_counter()
-        fn = build()
-        self.stats["trace_s"] += time.perf_counter() - t0
+        with span(self.spans, "plan.trace"):
+            fn = build()
         nbytes = 0
         if example_args is not None:
-            t0 = time.perf_counter()
-            fn = jax.jit(fn).lower(*example_args).compile()
-            self.stats["compile_s"] += time.perf_counter() - t0
+            with span(self.spans, "plan.compile"):
+                fn = jax.jit(fn).lower(*example_args).compile()
             nbytes = _exec_nbytes(fn)
         nbytes = nbytes or _EXEC_DEFAULT_NBYTES
         self._execs[key] = (fn, nbytes)
@@ -614,6 +618,11 @@ class PlanStore:
 
     def snapshot(self) -> dict:
         out = dict(self.stats)
+        # the keys plan.build_s reads; they predate the spans
+        out["lower_s"] = self.spans.get("plan.lower", {}).get("seconds", 0.0)
+        out["specialize_s"] = self.spans.get(
+            "plan.specialize", {}).get("seconds", 0.0)
+        out["spans"] = copy.deepcopy(self.spans)
         out["n_plans"] = self.n_plans
         out["n_execs"] = self.n_execs
         out["n_restorable"] = self.n_restorable

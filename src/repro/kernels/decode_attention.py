@@ -91,5 +91,6 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
             pltpu.VMEM((1,), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(clen, qt, kt, vt)
     return out.reshape(B, H, 1, hd).transpose(0, 2, 1, 3)

@@ -100,5 +100,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
         out_specs=pl.BlockSpec((1, bq, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

@@ -64,4 +64,5 @@ def grouped_ffn(x, w1, w3, w2, *, block_n: int = 128, block_f: int = 512,
         out_specs=pl.BlockSpec((1, bn, D), lambda e, n, f: (e, n, 0)),
         out_shape=jax.ShapeDtypeStruct((E, N, D), x.dtype),
         interpret=interpret,
+        name="grouped_matmul",
     )(x, w1, w3, w2)

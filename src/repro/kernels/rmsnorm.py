@@ -92,6 +92,7 @@ def fused_add_rmsnorm(x, y, g, *, eps: float = 1e-5, block_rows: int = 256,
             jax.ShapeDtypeStruct((n_pad, d), x.dtype),
         ],
         interpret=interpret,
+        name="add_rmsnorm",
     )(_pad_rows(x, n_pad), _pad_rows(y, n_pad), g.reshape(1, d))
     return s[:n], h[:n]
 
@@ -119,5 +120,6 @@ def rmsnorm(x, g, *, eps: float = 1e-5, block_rows: int = 256,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(_pad_rows(x, n_pad), g.reshape(1, d))
     return out[:n]

@@ -95,6 +95,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool = True):
         out_shape=jax.ShapeDtypeStruct((BH, nc, Q, P), x.dtype),
         scratch_shapes=[_vmem_scratch((N, P))],
         interpret=interpret,
+        name="ssd_scan",
     )(xt, dtt, Bt, Ct, At, Dt)
     return y.reshape(b, H, L, P).transpose(0, 2, 1, 3)
 

@@ -82,6 +82,14 @@ def serve(argv=None) -> list:
           f"({st['host_syncs']} host syncs / {st['decode_steps']} decode "
           f"steps, {st['row_moves']} row moves, "
           f"{st['chunk_steps']} chunk steps)")
+    iters = st["spans"].get("engine.iteration", {}).get("count", 0)
+    if iters:
+        phases = "  ".join(
+            f"{name[len('engine.'):]}={1e3 * c['seconds'] / iters:.3f}"
+            for name, c in st["spans"].items())
+        print(f"host ms per iteration by phase over {iters} iterations "
+              f"(prefill and chunk nest in admit, compact in decode, "
+              f"harvest_wait is the device_get): {phases}")
     ttfts = [r.first_token_s - r.submitted_s for r in done
              if r.first_token_s]
     if ttfts:

@@ -87,6 +87,8 @@ the same step functions in shard_map for the production mesh.
 """
 from __future__ import annotations
 
+import collections
+import copy
 import dataclasses
 import time
 from typing import Callable, Optional
@@ -99,6 +101,7 @@ from jax import lax
 from ..core.plan_store import PlanStore, resolve_plan_store
 from ..core.scheduler import ScheduleContext
 from ..models.base import build_forward
+from ..spans import span
 from .admission import (
     AdmissionContext,
     ChunkingDisabled,
@@ -144,6 +147,7 @@ class Request:
     output: list = dataclasses.field(default_factory=list)
     row: int = -1
     submitted_s: float = 0.0
+    admitted_s: float = 0.0            # first prefill or chunk dispatched
     first_token_s: float = 0.0
     done_s: float = 0.0
     result: object = None              # Finished | Shed | Failed
@@ -366,9 +370,11 @@ class ServeEngine:
         # chunk by chunk) but not yet decoding; round-robin queue
         self._chunking: list[dict] = []
         self.finished: list[Request] = []
-        # admission-order record: ("prefill", rids) / ("chunk", rid)
-        # tuples in dispatch order — the fairness contract's test surface
-        self.dispatch_log: list[tuple] = []
+        # admission-order record: ("prefill", rids) / ("chunk", rids)
+        # tuples in dispatch order — the fairness contract's test surface;
+        # bounded, since a server dispatches for as long as it lives
+        self.dispatch_log: collections.deque = collections.deque(
+            maxlen=4096)
         # device-resident loop state: the sampled token of every row's
         # last decode step, chained into the next step without touching
         # the host (the async half of the double-buffered loop)
@@ -383,16 +389,18 @@ class ServeEngine:
         self._stats = {"prefill_steps": 0, "prefill_reqs": 0,
                        "chunk_steps": 0, "decode_steps": 0,
                        "decode_tokens": 0, "host_syncs": 0, "row_moves": 0,
-                       "submitted": 0, "admitted": 0, "finished": 0,
+                       "submitted": 0, "finished": 0,
                        "shed": 0, "failed": 0, "preempted": 0,
                        "resumed": 0, "deadline_missed": 0,
                        "alloc_denied": 0, "page_denied": 0,
-                       "peak_active": 0, "stranded": 0, "drains": 0,
+                       "peak_active": 0, "stranded": 0,
                        "spec_steps": 0, "spec_drafted": 0,
                        "spec_accepted": 0, "spec_rollbacks": 0,
                        "spec_fallbacks": 0, "spec_builds": {},
                        "tier_steps": {t: 0 for t in self.tiers},
-                       "tier_builds": {}}
+                       "tier_builds": {}, "spans": {}}
+        # host time by phase: span counters named engine.* (repro.spans)
+        self._spans = self._stats["spans"]
         self._ck = self._cache_keys()
 
     # -- public -----------------------------------------------------------
@@ -435,7 +443,6 @@ class ServeEngine:
         if isinstance(decision, Shed):
             self._shed_request(req, decision.reason)
             return decision
-        self._stats["admitted"] += 1
         self.waiting.append(req)
         return None
 
@@ -446,23 +453,25 @@ class ServeEngine:
         it = self._iter
         self._iter += 1
         self._cur_iter = it
-        if self.faults is not None:
-            self.faults.on_iter(it)        # injected straggler
-        self._admit()
-        handle = self._dispatch_decode()
-        if self._spec is not None:
-            # speculative steps harvest synchronously: how far each row
-            # advanced (the accepted count) is data-dependent, so the
-            # host mirrors cannot move at dispatch time.  Still exactly
-            # one device_get per decode iteration.
-            self._harvest(handle)
-        elif self.cfg.async_host:
-            # double-buffered: step k+1 is now in flight; only then
-            # pay the (single) host sync for step k's tokens
-            prev, self._pending = self._pending, handle
-            self._harvest(prev)
-        else:
-            self._harvest(handle)
+        with span(self._spans, "engine.iteration", iter=it):
+            if self.faults is not None:
+                self.faults.on_iter(it)        # injected straggler
+            with span(self._spans, "engine.admit", iter=it):
+                self._admit()
+            handle = self._dispatch_decode()
+            if self._spec is not None:
+                # speculative steps harvest synchronously: how far each
+                # row advanced (the accepted count) is data-dependent, so
+                # the host mirrors cannot move at dispatch time.  Still
+                # exactly one device_get per decode iteration.
+                self._harvest(handle)
+            elif self.cfg.async_host:
+                # double-buffered: step k+1 is now in flight; only then
+                # pay the (single) host sync for step k's tokens
+                prev, self._pending = self._pending, handle
+                self._harvest(prev)
+            else:
+                self._harvest(handle)
         return self._busy()
 
     def run(self, max_iters: int = 10_000) -> list:
@@ -506,7 +515,6 @@ class ServeEngine:
                 self.step()
                 it += 1
             n = self.checkpoint()
-            self._stats["drains"] += 1
             return {"iters": it, "checkpointed": n,
                     "stranded": stranded,
                     "finished": self._stats["finished"],
@@ -556,8 +564,10 @@ class ServeEngine:
 
     @property
     def stats(self):
-        out = dict(self._stats)
-        out["tier_steps"] = dict(self._stats["tier_steps"])
+        """A snapshot of the counters, copied by value: it does not
+        change as the engine keeps stepping.  ``spans`` maps each
+        ``engine.*`` span to its ``{"count", "seconds"}`` so far."""
+        out = copy.deepcopy(self._stats)
         out["plan_store"] = self.store.snapshot()
         out["kv"] = self.cache.kv_stats()
         if self.faults is not None:
@@ -870,72 +880,79 @@ class ServeEngine:
             bp = self._tier_for(len(group), self.prefill_tiers)
             prompts = [r.effective_prompt for r in group]
             bucket = self._bucket(max(len(p) for p in prompts))
-            ids = np.zeros((bp, bucket), np.int32)
-            rows = np.full((bp,), group[0].row, np.int32)
-            full = np.zeros((bp,), bool)
-            sent_last = np.zeros((bp,), np.int32)
-            seeds = np.zeros((bp,), np.uint32)
-            rids = np.zeros((bp,), np.int32)
-            pos_emit = np.zeros((bp,), np.int32)
-            for j, (req, pr) in enumerate(zip(group, prompts)):
-                n = len(pr)
-                ids[j, :n] = pr[:n]
-                rows[j] = req.row
-                full[j] = n == bucket
-                sent_last[j] = int(pr[n - 1])
-                seeds[j] = self._req_seed(req)
-                rids[j] = req.rid
-                pos_emit[j] = n       # a full bucket emits position n
-                self._row_seed[req.row] = seeds[j]
-                self._row_rid[req.row] = req.rid
-            try:
-                if self.faults is not None:
-                    self.faults.check_dispatch(
-                        "prefill", [r.rid for r in group])
-                fn = self._prefill_fn(bp, bucket)
-                args = [self.params, jnp.asarray(ids), jnp.asarray(rows),
-                        jnp.asarray(full), jnp.asarray(sent_last),
-                        jnp.asarray(seeds), jnp.asarray(rids),
-                        jnp.asarray(pos_emit),
-                        self.cache.caches, self._last_ids]
-                if self.cache.paged:
-                    args.append(self.cache.page_table_array())
-                tok, self.cache.caches, self._last_ids = fn(*args)
-            except PoisonedRequest as e:
-                bad = next(r for r in group if r.rid == e.rid)
-                self._fail_request(bad, e)
-                group = [r for r in group if r is not bad]
-                continue
-            except Exception as e:                  # noqa: BLE001
-                for req in group:
-                    self._fail_request(req, f"prefill dispatch failed: {e}")
+            with span(self._spans, "engine.prefill", iter=self._cur_iter,
+                      bp=bp, bucket=bucket,
+                      rids=tuple(r.rid for r in group)):
+                ids = np.zeros((bp, bucket), np.int32)
+                rows = np.full((bp,), group[0].row, np.int32)
+                full = np.zeros((bp,), bool)
+                sent_last = np.zeros((bp,), np.int32)
+                seeds = np.zeros((bp,), np.uint32)
+                rids = np.zeros((bp,), np.int32)
+                pos_emit = np.zeros((bp,), np.int32)
+                for j, (req, pr) in enumerate(zip(group, prompts)):
+                    n = len(pr)
+                    ids[j, :n] = pr[:n]
+                    rows[j] = req.row
+                    full[j] = n == bucket
+                    sent_last[j] = int(pr[n - 1])
+                    seeds[j] = self._req_seed(req)
+                    rids[j] = req.rid
+                    pos_emit[j] = n       # a full bucket emits position n
+                    self._row_seed[req.row] = seeds[j]
+                    self._row_rid[req.row] = req.rid
+                try:
+                    if self.faults is not None:
+                        self.faults.check_dispatch(
+                            "prefill", [r.rid for r in group])
+                    fn = self._prefill_fn(bp, bucket)
+                    args = [self.params, jnp.asarray(ids), jnp.asarray(rows),
+                            jnp.asarray(full), jnp.asarray(sent_last),
+                            jnp.asarray(seeds), jnp.asarray(rids),
+                            jnp.asarray(pos_emit),
+                            self.cache.caches, self._last_ids]
+                    if self.cache.paged:
+                        args.append(self.cache.page_table_array())
+                    tok, self.cache.caches, self._last_ids = fn(*args)
+                except PoisonedRequest as e:
+                    bad = next(r for r in group if r.rid == e.rid)
+                    self._fail_request(bad, e)
+                    group = [r for r in group if r is not bad]
+                    continue
+                except Exception as e:                  # noqa: BLE001
+                    for req in group:
+                        self._fail_request(
+                            req, f"prefill dispatch failed: {e}")
+                    return
+                now = time.perf_counter()
+                slots = []
+                for j, (req, pr) in enumerate(zip(group, prompts)):
+                    n = len(pr)
+                    if not req.admitted_s:     # kept across a preemption
+                        req.admitted_s = now
+                    # tokens already generated pre-preemption count toward
+                    # max_new_tokens; a fresh request starts at 0
+                    base = len(req.output)
+                    if req._resume is not None:
+                        self._stats["resumed"] += 1
+                    self._gen[req.row] = base + (1 if full[j] else 0)
+                    self.cache.lengths[req.row] = n if full[j] else n - 1
+                    self.active[req.row] = req
+                    if full[j]:
+                        slots.append((j, req))
+                    else:
+                        # bucket-padded: the cache holds [0, n-1); the first
+                        # decode step re-runs the last token at position n-1
+                        # and yields the true next token (the -100 sentinel
+                        # routes the harvest down the replace path).
+                        req.output.append(-100)
+                self._stats["prefill_steps"] += 1
+                self._stats["prefill_reqs"] += len(group)
+                self.dispatch_log.append(("prefill",
+                                          tuple(r.rid for r in group)))
+                if slots:
+                    self._pending_prefill.append((tok, slots))
                 return
-            slots = []
-            for j, (req, pr) in enumerate(zip(group, prompts)):
-                n = len(pr)
-                # tokens already generated pre-preemption count toward
-                # max_new_tokens; a fresh request starts at 0
-                base = len(req.output)
-                if req._resume is not None:
-                    self._stats["resumed"] += 1
-                self._gen[req.row] = base + (1 if full[j] else 0)
-                self.cache.lengths[req.row] = n if full[j] else n - 1
-                self.active[req.row] = req
-                if full[j]:
-                    slots.append((j, req))
-                else:
-                    # bucket-padded: the cache holds [0, n-1); the first
-                    # decode step re-runs the last token at position n-1
-                    # and yields the true next token (the -100 sentinel
-                    # routes the harvest down the replace path).
-                    req.output.append(-100)
-            self._stats["prefill_steps"] += 1
-            self._stats["prefill_reqs"] += len(group)
-            self.dispatch_log.append(("prefill",
-                                      tuple(r.rid for r in group)))
-            if slots:
-                self._pending_prefill.append((tok, slots))
-            return
 
     def _prefill_fn(self, bp: int, bucket: int) -> Callable:
         def build():
@@ -985,7 +1002,7 @@ class ServeEngine:
                             jnp.where(full[j], tok[j], sent_last[j]))
                     return tok, caches, li[:, None]
 
-                return _jit(run, donate=(8, 9))
+                return _jit(run, f"prefill_b{bp}_s{bucket}", donate=(8, 9))
 
             def run(params, ids, rows, full, sent_last, seeds, rids,
                     pos_emit, caches, last_ids):
@@ -1019,7 +1036,7 @@ class ServeEngine:
                         jnp.where(full[j], tok[j], sent_last[j]))
                 return tok, caches, li[:, None]
 
-            return _jit(run, donate=(8, 9))
+            return _jit(run, f"prefill_b{bp}_s{bucket}", donate=(8, 9))
 
         return self.store.get_or_build(
             ("prefill", self._cache_tag, self._samp_salt, bp, bucket),
@@ -1103,54 +1120,61 @@ class ServeEngine:
                 keep.append(st)
         self._chunking = keep
         bc = self._tier_for(len(batch), self.prefill_tiers)
-        ids = np.zeros((bc, c), np.int32)
-        offs = np.zeros((bc,), np.int32)
-        rows = np.full((bc,), batch[0]["req"].row, np.int32)
-        for j, st in enumerate(batch):
-            off = st["chunks"][st["next"]][0]
-            ids[j] = st["padded"][off:off + c]
-            offs[j] = off
-            rows[j] = st["req"].row
-        # padded slots duplicate slot 0: identical writes are order-safe
-        for j in range(len(batch), bc):
-            ids[j], offs[j] = ids[0], offs[0]
-        try:
-            if self.faults is not None:
-                self.faults.check_dispatch(
-                    "chunk", [st["req"].rid for st in batch])
-            fn = self._chunk_fn(bc, c)
-            args = [self.params, jnp.asarray(ids), jnp.asarray(offs),
-                    jnp.asarray(rows), self.cache.caches]
-            if self.cache.paged:
-                args.append(self.cache.page_table_array())
-            self.cache.caches = fn(*args)
-        except Exception as e:                      # noqa: BLE001
-            for st in batch:
-                self._fail_request(st["req"], f"chunk dispatch failed: {e}")
-            return
-        self._stats["chunk_steps"] += 1
-        self.dispatch_log.append(
-            ("chunk", tuple(st["req"].rid for st in batch)))
-        for j, st in enumerate(batch):
-            req, row = st["req"], st["req"].row
-            off = int(offs[j])
-            st["next"] += 1
-            if st["next"] < len(st["chunks"]):
-                # keep the host length mirror at the chunk frontier: a
-                # decode step interleaved before the next chunk writes
-                # one garbage k/v at this position for the (inactive)
-                # row, and the next chunk's full-slab write overwrites it
-                self.cache.lengths[row] = off + c
-                self._chunking.append(st)      # round-robin: to the back
-                continue
-            prompt = st["prompt"]
-            n = len(prompt)
-            self._last_ids = self._last_ids.at[row, 0].set(
-                int(prompt[n - 1]))
-            self.cache.lengths[row] = n - 1
-            self._gen[row] = len(req.output)
-            req.output.append(-100)
-            self.active[row] = req
+        with span(self._spans, "engine.chunk", iter=self._cur_iter,
+                  bc=bc, chunk=c,
+                  rids=tuple(st["req"].rid for st in batch)):
+            ids = np.zeros((bc, c), np.int32)
+            offs = np.zeros((bc,), np.int32)
+            rows = np.full((bc,), batch[0]["req"].row, np.int32)
+            for j, st in enumerate(batch):
+                off = st["chunks"][st["next"]][0]
+                ids[j] = st["padded"][off:off + c]
+                offs[j] = off
+                rows[j] = st["req"].row
+            # padded slots duplicate slot 0: identical writes are order-safe
+            for j in range(len(batch), bc):
+                ids[j], offs[j] = ids[0], offs[0]
+            try:
+                if self.faults is not None:
+                    self.faults.check_dispatch(
+                        "chunk", [st["req"].rid for st in batch])
+                fn = self._chunk_fn(bc, c)
+                args = [self.params, jnp.asarray(ids), jnp.asarray(offs),
+                        jnp.asarray(rows), self.cache.caches]
+                if self.cache.paged:
+                    args.append(self.cache.page_table_array())
+                self.cache.caches = fn(*args)
+            except Exception as e:                      # noqa: BLE001
+                for st in batch:
+                    self._fail_request(st["req"],
+                                       f"chunk dispatch failed: {e}")
+                return
+            now = time.perf_counter()
+            self._stats["chunk_steps"] += 1
+            self.dispatch_log.append(
+                ("chunk", tuple(st["req"].rid for st in batch)))
+            for j, st in enumerate(batch):
+                req, row = st["req"], st["req"].row
+                if not req.admitted_s:
+                    req.admitted_s = now
+                off = int(offs[j])
+                st["next"] += 1
+                if st["next"] < len(st["chunks"]):
+                    # keep the host length mirror at the chunk frontier: a
+                    # decode step interleaved before the next chunk writes
+                    # one garbage k/v at this position for the (inactive)
+                    # row, and the next chunk's full-slab write overwrites it
+                    self.cache.lengths[row] = off + c
+                    self._chunking.append(st)      # round-robin: to the back
+                    continue
+                prompt = st["prompt"]
+                n = len(prompt)
+                self._last_ids = self._last_ids.at[row, 0].set(
+                    int(prompt[n - 1]))
+                self.cache.lengths[row] = n - 1
+                self._gen[row] = len(req.output)
+                req.output.append(-100)
+                self.active[row] = req
 
     def _chunk_fn(self, bc: int, chunk: int) -> Callable:
         def build():
@@ -1193,7 +1217,7 @@ class ServeEngine:
                             chunk))
                     return new
 
-                return _jit(run, donate=(4,))
+                return _jit(run, f"chunk_b{bc}_s{chunk}", donate=(4,))
 
             def run(params, ids, offs, rows, caches):
                 pos = offs[:, None] \
@@ -1212,7 +1236,7 @@ class ServeEngine:
                             axis=bds[k])
                 return new
 
-            return _jit(run, donate=(4,))
+            return _jit(run, f"chunk_b{bc}_s{chunk}", donate=(4,))
 
         return self.store.get_or_build(
             ("chunk", self._cache_tag, bc, chunk), build)
@@ -1267,7 +1291,7 @@ class ServeEngine:
                     done = active & (will_end | (tok == eos))
                     return tok, done, tok[:, None], new_caches
 
-                return _jit(run, donate=(1, 8))
+                return _jit(run, f"decode_t{tier}", donate=(1, 8))
 
             def run(params, last_ids, cache_len, active, eos, will_end,
                     seeds, rids, caches):
@@ -1292,7 +1316,7 @@ class ServeEngine:
                 done = active & (will_end | (tok == eos))
                 return tok, done, tok[:, None], new_caches
 
-            return _jit(run, donate=(1, 8))
+            return _jit(run, f"decode_t{tier}", donate=(1, 8))
 
         return self.store.get_or_build(
             ("decode", self._cache_tag, self._samp_salt, tier), build)
@@ -1303,23 +1327,26 @@ class ServeEngine:
         partially-filled cache rows relocate the same way (cache rows
         move on-device; the in-flight step, if any, ordered ahead by
         data dependencies)."""
-        chunk_rows = {st["req"].row: st for st in self._chunking}
-        occupied = sorted((r for r in (*self.active, *chunk_rows)
-                           if r >= tier), reverse=True)
-        for src in occupied:
-            dst = next(r for r in self.cache.free_rows if r < tier)
-            self.cache.move_row(src, dst)
-            self._last_ids = self._last_ids.at[dst].set(self._last_ids[src])
-            self._gen[dst] = self._gen[src]
-            self._row_seed[dst] = self._row_seed[src]
-            self._row_rid[dst] = self._row_rid[src]
-            if src in self.active:
-                req = self.active.pop(src)
-                req.row = dst
-                self.active[dst] = req
-            else:
-                chunk_rows[src]["req"].row = dst
-            self._stats["row_moves"] += 1
+        with span(self._spans, "engine.compact", iter=self._cur_iter,
+                  tier=tier):
+            chunk_rows = {st["req"].row: st for st in self._chunking}
+            occupied = sorted((r for r in (*self.active, *chunk_rows)
+                               if r >= tier), reverse=True)
+            for src in occupied:
+                dst = next(r for r in self.cache.free_rows if r < tier)
+                self.cache.move_row(src, dst)
+                self._last_ids = self._last_ids.at[dst].set(
+                    self._last_ids[src])
+                self._gen[dst] = self._gen[src]
+                self._row_seed[dst] = self._row_seed[src]
+                self._row_rid[dst] = self._row_rid[src]
+                if src in self.active:
+                    req = self.active.pop(src)
+                    req.row = dst
+                    self.active[dst] = req
+                else:
+                    chunk_rows[src]["req"].row = dst
+                self._stats["row_moves"] += 1
 
     def _ensure_decode_pages(self):
         """Paged backends only: every active row writes position
@@ -1371,61 +1398,64 @@ class ServeEngine:
             # in the prefix (their frontier-position garbage writes are
             # overwritten by the next chunk — see _step_chunked)
             tier = self._tier_for(occ, self.tiers)
-            self._compact(tier)
-            if self._spec is not None:
-                k = self._spec_k_for_dispatch()
-                if k:
-                    result = self._dispatch_spec(tier, k)
-                    if result == "retry":
-                        continue
-                    return result
-                self._stats["spec_fallbacks"] += 1
-            active = np.zeros((B,), bool)
-            will_end = np.zeros((B,), bool)
-            eos = np.full((B,), -1, np.int32)
-            snapshot = []
-            for row, req in self.active.items():
-                active[row] = True
-                eos[row] = req.eos_id
-                will_end[row] = (self._gen[row] + 1 >= req.max_new_tokens
-                                 or self.cache.lengths[row] + 1
-                                 >= self.cfg.s_max - 1)
-                snapshot.append((row, req))
-            try:
-                if self.faults is not None:
-                    self.faults.check_dispatch(
-                        "decode", [r.rid for _, r in snapshot])
-                fn = self._decode_fn(tier)
-                # .copy(): on CPU jnp.asarray may alias the host buffer,
-                # and these mirrors mutate between dispatch and execute
-                args = [self.params, self._last_ids,
-                        self.cache.cache_len_array(),
-                        jnp.asarray(active), jnp.asarray(eos),
-                        jnp.asarray(will_end),
-                        jnp.asarray(self._row_seed.copy()),
-                        jnp.asarray(self._row_rid.copy()),
-                        self.cache.caches]
-                if self.cache.paged:
-                    args.append(self.cache.page_table_array())
-                tok, done, self._last_ids, self.cache.caches = fn(*args)
-            except PoisonedRequest as e:
-                bad = next(r for _, r in snapshot if r.rid == e.rid)
-                self._fail_request(bad, e)
-                continue
-            except Exception as e:                  # noqa: BLE001
-                for _, req in snapshot:
-                    self._fail_request(req, f"decode dispatch failed: {e}")
-                return None
-            # host mirrors advance at dispatch, not harvest: the device's
-            # view of every row is derivable without a sync
-            for row, _ in snapshot:
-                self.cache.lengths[row] += 1
-                self._gen[row] += 1
-            self._stats["decode_steps"] += 1
-            self._stats["tier_steps"][tier] += 1
-            if self._observer is not None:
-                self._feed_observer(tier)
-            return (tok, done, snapshot)
+            with span(self._spans, "engine.decode", iter=self._cur_iter,
+                      tier=tier,
+                      rids=tuple(r.rid for r in self.active.values())):
+                self._compact(tier)
+                if self._spec is not None:
+                    k = self._spec_k_for_dispatch()
+                    if k:
+                        result = self._dispatch_spec(tier, k)
+                        if result == "retry":
+                            continue
+                        return result
+                    self._stats["spec_fallbacks"] += 1
+                active = np.zeros((B,), bool)
+                will_end = np.zeros((B,), bool)
+                eos = np.full((B,), -1, np.int32)
+                snapshot = []
+                for row, req in self.active.items():
+                    active[row] = True
+                    eos[row] = req.eos_id
+                    will_end[row] = (self._gen[row] + 1 >= req.max_new_tokens
+                                     or self.cache.lengths[row] + 1
+                                     >= self.cfg.s_max - 1)
+                    snapshot.append((row, req))
+                try:
+                    if self.faults is not None:
+                        self.faults.check_dispatch(
+                            "decode", [r.rid for _, r in snapshot])
+                    fn = self._decode_fn(tier)
+                    # .copy(): on CPU jnp.asarray may alias the host buffer,
+                    # and these mirrors mutate between dispatch and execute
+                    args = [self.params, self._last_ids,
+                            self.cache.cache_len_array(),
+                            jnp.asarray(active), jnp.asarray(eos),
+                            jnp.asarray(will_end),
+                            jnp.asarray(self._row_seed.copy()),
+                            jnp.asarray(self._row_rid.copy()),
+                            self.cache.caches]
+                    if self.cache.paged:
+                        args.append(self.cache.page_table_array())
+                    tok, done, self._last_ids, self.cache.caches = fn(*args)
+                except PoisonedRequest as e:
+                    bad = next(r for _, r in snapshot if r.rid == e.rid)
+                    self._fail_request(bad, e)
+                    continue
+                except Exception as e:                  # noqa: BLE001
+                    for _, req in snapshot:
+                        self._fail_request(req, f"decode dispatch failed: {e}")
+                    return None
+                # host mirrors advance at dispatch, not harvest: the device's
+                # view of every row is derivable without a sync
+                for row, _ in snapshot:
+                    self.cache.lengths[row] += 1
+                    self._gen[row] += 1
+                self._stats["decode_steps"] += 1
+                self._stats["tier_steps"][tier] += 1
+                if self._observer is not None:
+                    self._feed_observer(tier)
+                return (tok, done, snapshot)
         return None
 
     def _feed_observer(self, tier: int):
@@ -1665,7 +1695,7 @@ class ServeEngine:
                         caches, out, page_tab, cache_len, tier, W)
                     return u, n_emit, done, li[:, None], new_caches
 
-                return _jit(run, donate=(1, 9))
+                return _jit(run, f"spec_verify_t{tier}_k{k}", donate=(1, 9))
 
             def run(params, last_ids, cache_len, active, eos, gen_left,
                     seeds, rids, drafts, caches):
@@ -1683,7 +1713,7 @@ class ServeEngine:
                     for ck in caches}
                 return u, n_emit, done, li[:, None], new_caches
 
-            return _jit(run, donate=(1, 9))
+            return _jit(run, f"spec_verify_t{tier}_k{k}", donate=(1, 9))
 
         return self.store.get_or_build(
             ("spec_verify", self._cache_tag, self._spec_salt, tier, k),
@@ -1758,7 +1788,7 @@ class ServeEngine:
                                 lax.slice_in_dim(rids, 0, tier, axis=0),
                                 tcaches)
 
-                return _jit(run)
+                return _jit(run, f"spec_draft_t{tier}_k{k}")
 
             def run(params, last_ids, cache_len, seeds, rids, caches):
                 clen = lax.slice_in_dim(cache_len, 0, tier, axis=0)
@@ -1769,7 +1799,7 @@ class ServeEngine:
                             lax.slice_in_dim(rids, 0, tier, axis=0),
                             tcaches)
 
-            return _jit(run)
+            return _jit(run, f"spec_draft_t{tier}_k{k}")
 
         return self.store.get_or_build(
             ("spec_draft", self._cache_tag, self._spec_salt,
@@ -1795,55 +1825,57 @@ class ServeEngine:
             fetch = []
         i = len(fetch)
         fetch.extend(t for t, _ in prefills)
-        vals = jax.device_get(fetch)
+        with span(self._spans, "engine.harvest_wait", iter=self._cur_iter):
+            vals = jax.device_get(fetch)
         self._stats["host_syncs"] += 1
         now = time.perf_counter()
-        # prefill first: in sync mode the same harvest also carries the
-        # first decode step of the just-admitted rows
-        for (_, slots), toks in zip(prefills, vals[i:]):
-            for j, req in slots:
-                if req.done_s:
-                    continue
+        with span(self._spans, "engine.harvest", iter=self._cur_iter):
+            # prefill first: in sync mode the same harvest also carries the
+            # first decode step of the just-admitted rows
+            for (_, slots), toks in zip(prefills, vals[i:]):
+                for j, req in slots:
+                    if req.done_s:
+                        continue
+                    try:
+                        if self.faults is not None:
+                            self.faults.check_harvest(req.rid)
+                        req.output.append(int(toks[j]))
+                        if not req.first_token_s:
+                            req.first_token_s = now
+                        if (len(req.output) >= req.max_new_tokens
+                                or req.output[-1] == req.eos_id):
+                            self._finish(req, now)
+                        elif self._deadline_blown(req, now):
+                            self._fail_deadline(req, now)
+                    except Exception as e:              # noqa: BLE001
+                        self._fail_request(req, f"harvest failed: {e}")
+            if pending is None:
+                return
+            if spec:
+                self._harvest_spec(vals, pending, now)
+                return
+            tok, done, snapshot = np.asarray(vals[0]), np.asarray(vals[1]), \
+                pending[2]
+            for row, req in snapshot:
+                if req.done_s:       # finished by an earlier harvest: the
+                    continue         # in-flight step decoded a stale row
                 try:
                     if self.faults is not None:
                         self.faults.check_harvest(req.rid)
-                    req.output.append(int(toks[j]))
-                    if not req.first_token_s:
-                        req.first_token_s = now
-                    if (len(req.output) >= req.max_new_tokens
-                            or req.output[-1] == req.eos_id):
+                    t = int(tok[row])
+                    if req.output and req.output[-1] == -100:
+                        req.output[-1] = t     # sentinel: first real token
+                        if not req.first_token_s:
+                            req.first_token_s = now
+                    else:
+                        req.output.append(t)
+                    self._stats["decode_tokens"] += 1
+                    if done[row]:
                         self._finish(req, now)
                     elif self._deadline_blown(req, now):
                         self._fail_deadline(req, now)
-                except Exception as e:              # noqa: BLE001
+                except Exception as e:                  # noqa: BLE001
                     self._fail_request(req, f"harvest failed: {e}")
-        if pending is None:
-            return
-        if spec:
-            self._harvest_spec(vals, pending, now)
-            return
-        tok, done, snapshot = np.asarray(vals[0]), np.asarray(vals[1]), \
-            pending[2]
-        for row, req in snapshot:
-            if req.done_s:       # finished by an earlier harvest: the
-                continue         # in-flight step decoded a stale row
-            try:
-                if self.faults is not None:
-                    self.faults.check_harvest(req.rid)
-                t = int(tok[row])
-                if req.output and req.output[-1] == -100:
-                    req.output[-1] = t     # sentinel: first real token
-                    if not req.first_token_s:
-                        req.first_token_s = now
-                else:
-                    req.output.append(t)
-                self._stats["decode_tokens"] += 1
-                if done[row]:
-                    self._finish(req, now)
-                elif self._deadline_blown(req, now):
-                    self._fail_deadline(req, now)
-            except Exception as e:                  # noqa: BLE001
-                self._fail_request(req, f"harvest failed: {e}")
 
     def _harvest_spec(self, vals, pending, now: float):
         """Apply one verify step's results: append each row's accepted
@@ -1918,9 +1950,12 @@ class ServeEngine:
         return out
 
 
-def _jit(fn, donate: tuple = ()):
+def _jit(fn, name: str, donate: tuple = ()):
     """jit with buffer donation where the backend supports it (donation
-    is a no-op warning on CPU, so skip it there to keep test logs clean)."""
+    is a no-op warning on CPU, so skip it there to keep test logs clean).
+    ``name`` names the device program: it lowers as ``jit_<name>``, so a
+    profile tells step kinds and shapes apart."""
+    fn.__name__ = fn.__qualname__ = name
     if donate and jax.default_backend() != "cpu":
         return jax.jit(fn, donate_argnums=donate)
     return jax.jit(fn)

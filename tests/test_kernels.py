@@ -254,3 +254,34 @@ def test_tokenweave_fused_unsharded():
     s2, h2 = ref.fused_add_rmsnorm(x, y, g)
     np.testing.assert_allclose(s, s2, atol=1e-5)
     np.testing.assert_allclose(h, h2, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# kernel names: a profile finds each kernel by the name its call carries
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("name,fn,shapes", [
+    ("add_rmsnorm", lambda x, y, g: ops.fused_add_rmsnorm(x, y, g)[0],
+     [(8, 16), (8, 16), (16,)]),
+    ("rmsnorm", ops.rmsnorm, [(8, 16), (16,)]),
+    ("flash_attention", ops.flash_attention, [(1, 32, 2, 16)] * 3),
+    ("decode_attention", lambda q, k, v: ops.decode_attention(q, k, v, 4),
+     [(1, 1, 2, 16), (1, 32, 2, 16), (1, 32, 2, 16)]),
+    ("grouped_matmul", ops.grouped_ffn,
+     [(2, 16, 24), (2, 24, 32), (2, 24, 32), (2, 32, 24)]),
+    ("ssd_scan", lambda x, dt, b, c: ops.ssd_scan(
+        x, dt, jnp.ones((2,)), b, c, jnp.ones((2,)), chunk=8),
+     [(1, 16, 2, 8), (1, 16, 2), (1, 16, 1, 4), (1, 16, 1, 4)]),
+])
+def test_every_pallas_call_is_named(name, fn, shapes):
+    args = [jnp.ones(s, jnp.float32) for s in shapes]
+    eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
+    found = []
+    while eqns:                       # the call may sit inside a custom_vjp
+        e = eqns.pop()
+        if e.primitive.name == "pallas_call":
+            found.append(e.params["name"])
+        for sub in jax.core.jaxprs_in_params(e.params):
+            eqns.extend(sub.eqns)
+    assert found == [name]

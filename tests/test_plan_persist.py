@@ -123,6 +123,7 @@ def test_redeemed_then_evicted_entry_survives_checkpoint(tmp_path):
     assert warm.stats["evictions"] == 1
     warm.get_or_lower(g, plan, salt="t")            # redeems again, no miss
     assert warm.stats["restore_hits"] == 2
+    assert warm.spans["plan.restore"]["count"] == 2
     assert warm.stats["misses"] == 1                # only the g2 structure
     path2 = str(tmp_path / "store2.dfps")
     warm.get_or_lower(g2, p2, salt="t")             # evict the redeem again
@@ -242,6 +243,9 @@ def test_schema_malformed_entry_degrades_to_cold_lower(tmp_path):
     lowered = store.get_or_lower(g, plan, salt="t")
     assert store.stats["restore_rejected"] >= 1
     assert store.stats["misses"] == 1
+    # a rejected restore is timed apart from the successful ones
+    assert store.spans["plan.restore_rejected"]["count"] >= 1
+    assert "plan.restore" not in store.spans
     _assert_same(Realizer(g, plan, lowered=False)(params, {"x": x}),
                  lowered(params, {"x": x}))
 

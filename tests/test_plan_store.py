@@ -197,6 +197,10 @@ def test_specialize_fallback_is_counted(monkeypatch):
     lowered = store.get_or_lower(g2, p2)
     assert store.stats["specialize_rejects"] == 1
     assert store.stats["misses"] == 2           # fell back to a cold lower
+    # the rejected attempt is timed apart: specialize_s keeps its meaning
+    assert store.spans["plan.specialize_rejected"]["count"] == 1
+    assert "plan.specialize" not in store.spans
+    assert store.snapshot()["specialize_s"] == 0.0
     _assert_same(Realizer(g2, p2, lowered=False)(params, {"x": x}),
                  lowered(params, {"x": x}))
 

@@ -57,7 +57,10 @@ def test_fused_add_rmsnorm_compiles(one_chip, d, block_rows):
                                     interpret=False)
 
     compiled = jax.jit(f).lower(x, x, g).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name= names its instruction, which a profile shows
+    assert "%add_rmsnorm" in text
 
 
 def test_smollm_decode_step_compiles(one_chip):
@@ -73,3 +76,29 @@ def test_smollm_decode_step_compiles(one_chip):
     used = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes
     assert 0 < used < 16e9
+
+
+@pytest.mark.parametrize("name", ["add_rmsnorm", "rmsnorm",
+                                  "flash_attention", "grouped_matmul"])
+def test_kernel_name_reaches_tpu_lowering(one_chip, name):
+    """Decode attention and the SSD scan are left out: the TPU lowering
+    refuses them whatever their name (a VMEM scalar store, cumsum)."""
+    from repro.kernels import flash_attention, grouped_matmul
+    bf = jnp.bfloat16
+    fn, shapes = {
+        "add_rmsnorm": (lambda x, y, g: rn.fused_add_rmsnorm(
+            x, y, g, interpret=False),
+            [(256, 576), (256, 576), (576,)]),
+        "rmsnorm": (lambda x, g: rn.rmsnorm(x, g, interpret=False),
+                    [(256, 576), (576,)]),
+        "flash_attention": (
+            lambda q, k, v: flash_attention.flash_attention(
+                q, k, v, interpret=False), [(1, 256, 4, 128)] * 3),
+        "grouped_matmul": (
+            lambda x, w1, w3, w2: grouped_matmul.grouped_ffn(
+                x, w1, w3, w2, interpret=False),
+            [(2, 128, 256), (2, 256, 512), (2, 256, 512), (2, 512, 256)]),
+    }[name]
+    args = [jax.ShapeDtypeStruct(s, bf, sharding=one_chip) for s in shapes]
+    text = jax.jit(fn).lower(*args).as_text()
+    assert f'kernel_name = "{name}"' in text
