@@ -525,6 +525,29 @@ def _sdpa(q, k, v, causal: bool, q_offset=0, valid_len=None):
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
+def _sdpa_grouped(q, k, v, valid_len):
+    """``_sdpa`` without causal masking for grouped-query attention: local
+    q head ``i`` reads KV slot ``i // g``.  q (B,Sq,H,hd), k/v
+    (B,Sk,kv,hd) with ``H = kv * g``; ``valid_len`` (B,) or (B,Sq).
+
+    K and V are read once per KV head: the scores are one dot per (row,
+    KV head) with the group's ``g * Sq`` query rows as M, not a
+    multiply-reduce over K and V copied to every query head."""
+    B, Sq, H, hd = q.shape
+    Sk, kv = k.shape[1], k.shape[2]
+    qg = q.reshape(B, Sq, kv, H // kv, hd)
+    scale = 1.0 / math.sqrt(hd)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    vl = jnp.asarray(valid_len)
+    vl = (vl[:, None, None, :, None] if vl.ndim == 2     # (B,Sq)
+          else vl.reshape(-1, 1, 1, 1, 1))              # (B,)
+    logits = jnp.where(jnp.arange(Sk) < vl, logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
+    return out.reshape(B, Sq, H, hd)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _sdpa_chunked(q, k, v, causal: bool, chunk_q: int):
     """Exact attention, scanned over q blocks (bounded peak memory).
@@ -671,6 +694,8 @@ class DecodeAttentionOp(Op):
             cache_len (B,) int32 per-request lengths (ragged batch).
     Outputs: attn (B,1,q_local,hd), updated k_cache, v_cache.
     ``impl='pallas'`` uses the flash-decode kernel for the cache read.
+    Otherwise a ``grouped`` layout reads the cache once per KV head
+    (``_sdpa_grouped``); other layouts copy K and V to every q head.
     """
 
     resource = "memory"
@@ -683,6 +708,16 @@ class DecodeAttentionOp(Op):
         self.impl = impl
         self.named(name)
 
+    @property
+    def grouped(self) -> bool:
+        """True when, on every shard, local q head ``i`` reads KV slot
+        ``i // g`` with ``g = q_local // kv_local``: each slot serves one
+        contiguous group of q heads, padding heads included."""
+        lay = self.layout
+        g, rem = divmod(lay.q_local, lay.kv_local)
+        return rem == 0 and bool(
+            (lay.q_slot_map() == np.arange(lay.q_local) // g).all())
+
     def kernel(self, p, q, k_new, v_new, k_cache, v_cache, cache_len):
         lay = self.layout
         clen = (jnp.broadcast_to(cache_len, (q.shape[0],))
@@ -691,12 +726,12 @@ class DecodeAttentionOp(Op):
         v_cache = _dus_time(v_cache, v_new, clen)
         slot = jnp.asarray(lay.q_slot_map())[col.axis_index("model")]
         valid = jnp.asarray(lay.q_valid_map())[col.axis_index("model")]
-        k_per_q = jnp.take(k_cache, slot, axis=2)
-        v_per_q = jnp.take(v_cache, slot, axis=2)
+        per_q = functools.partial(jnp.take, indices=slot, axis=2)
         Sq = q.shape[1]
         if self.impl == "pallas" and Sq == 1:
             from ..kernels import ops as kops
-            out = kops.decode_attention(q, k_per_q, v_per_q, clen + 1)
+            out = kops.decode_attention(q, per_q(k_cache), per_q(v_cache),
+                                        clen + 1)
         else:
             # chunked decode (Sq > 1): query position j attends the cache
             # prefix plus the chunk up to and including itself —
@@ -704,7 +739,11 @@ class DecodeAttentionOp(Op):
             vl = (clen + 1 if Sq == 1
                   else clen[:, None] + 1 + jnp.arange(Sq, dtype=clen.dtype))
             with jax.named_scope("flashable_decode"):
-                out = _sdpa(q, k_per_q, v_per_q, causal=False, valid_len=vl)
+                if self.grouped:
+                    out = _sdpa_grouped(q, k_cache, v_cache, vl)
+                else:
+                    out = _sdpa(q, per_q(k_cache), per_q(v_cache),
+                                causal=False, valid_len=vl)
         out = out * valid[None, None, :, None].astype(out.dtype)
         return out, k_cache, v_cache
 

@@ -102,3 +102,26 @@ def test_kernel_name_reaches_tpu_lowering(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, bf, sharding=one_chip) for s in shapes]
     text = jax.jit(fn).lower(*args).as_text()
     assert f'kernel_name = "{name}"' in text
+
+
+def test_grouped_decode_attention_compiles_without_expansion(one_chip):
+    """chatglm3-6b's decode attention (32 q heads over 2 KV heads) at 16
+    rows x 2048 positions keeps no copy of K or V per q head: one such
+    copy is 256 MiB, the grouped program's scratch a fraction of 1 MiB."""
+    from repro.models.layers import DecodeAttentionOp, HeadLayout
+    op = DecodeAttentionOp(HeadLayout(32, 2, 1, 128))
+    assert op.grouped
+    B, S = 16, 2048
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    new = sds((B, 1, 2, 128))
+    cache = sds((B, S, 2, 128))
+    compiled = jax.jit(lambda *a: op.kernel({}, *a),
+                       donate_argnums=(3, 4)).lower(
+        sds((B, 1, 32, 128)), new, new, cache, cache,
+        sds((B,), jnp.int32)).compile()
+    per_q_copy = B * S * 32 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < per_q_copy / 64
+    assert " gather(" not in compiled.as_text()
