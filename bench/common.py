@@ -4,7 +4,11 @@ compile counting and device facts.
 The harness is driven by data.  ``BENCHMARK.json`` names a cell's
 configuration and traffic mix; their files are
 ``bench/configs/<config>.json`` and ``bench/traffic/<traffic>.json``, and
-each per-layer metric is ``bench/metrics/<metric>.py``.
+each per-layer metric is ``bench/metrics/<metric>.py``.  A configuration
+names its model family (``"family"``, ``dense`` where absent):
+``bench/families/<family>.py``, whose plain reference is
+``bench/reference/<REFERENCE>.py``.  Each loader looks in ``bench_dir``,
+which defaults to :data:`BENCH`.
 """
 from __future__ import annotations
 
@@ -40,18 +44,45 @@ def load_config(bench: dict, name: str) -> dict:
     return json.loads(config_file(bench, name).read_text())
 
 
-def load_traffic(name: str, bench_dir: Path = BENCH) -> dict:
-    return json.loads((bench_dir / "traffic" / f"{name}.json").read_text())
+def load_traffic(name: str, bench_dir: Path | None = None) -> dict:
+    path = (bench_dir or BENCH) / "traffic" / f"{name}.json"
+    return json.loads(path.read_text())
 
 
-def load_metric_reader(name: str, bench_dir: Path = BENCH):
+_MODULES: dict = {}
+
+
+def _load(kind: str, name: str, bench_dir: Path | None):
+    """``bench/<kind>/<name>.py`` as a module, loaded once a process."""
+    key = (bench_dir or BENCH, kind, name)
+    if key not in _MODULES:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"),
+            key[0] / kind / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _MODULES[key] = mod
+    return _MODULES[key]
+
+
+def load_metric_reader(name: str, bench_dir: Path | None = None):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load("metrics", name, bench_dir).read
+
+
+def load_family(name: str, bench_dir: Path | None = None):
+    """The module ``bench/families/<name>.py``."""
+    return _load("families", name, bench_dir)
+
+
+def family(m: dict):
+    """The family module of a model dict (:func:`model_dims`)."""
+    return load_family(m.get("family", "dense"))
+
+
+def reference(m: dict):
+    """The plain reference module of a model dict's family."""
+    return _load("reference", family(m).REFERENCE, None)
 
 
 def cell_metrics(bench: dict, cell: str, kind: str) -> list:
@@ -65,7 +96,19 @@ def model_dims(cfg: dict) -> dict:
     file's ``keys`` map from the published names."""
     m = {ours: cfg[theirs] for ours, theirs in cfg["keys"].items()}
     m.setdefault("head_dim", m["d_model"] // m["n_heads"])
+    m["family"] = cfg.get("family", "dense")
     return m
+
+
+def cell_mesh(devices):
+    """A ``("data", "model")`` mesh of shape (1, chips) over exactly the
+    cell's devices, or None for one chip."""
+    if len(devices) == 1:
+        return None
+    import jax
+    return jax.make_mesh((1, len(devices)), ("data", "model"),
+                         devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def seed_key(seed: int):

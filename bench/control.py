@@ -69,14 +69,17 @@ def main(argv=None) -> int:
     mix = common.load_traffic(cell["traffic"])
     import jax
     devices = jax.devices()
-    if devices[0].platform != "tpu":
-        print("control: JAX found no TPU", file=sys.stderr)
+    if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
+        print(f"control: {cell['name']} needs {cell['chips']} TPU chips, "
+              f"JAX found {len(devices)} {devices[0].platform} devices",
+              file=sys.stderr)
         return 2
     if args.half_batch:
         plant_half_batch()
     from bench import run
     for seed in (int(s) for s in args.seeds.split(",")):
-        res = run.measure(cfg, mix, seed, args.seconds, False, devices[:1],
+        res = run.measure(cfg, mix, seed, args.seconds, False,
+                          devices[:cell["chips"]],
                           time.perf_counter(), control=not args.half_batch)
         line = run.result_line(bench, cell, res)
         chk = res["check"]

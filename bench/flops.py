@@ -1,24 +1,24 @@
-"""Operations and bytes of a dense decoder, computed from its shapes.
+"""Operations and bytes of a decoder, computed from its shapes, for the
+whole model (over all the chips that hold it).
 
 ``m`` is the model dict :func:`common.model_dims` gives: ``n_layers``,
-``d_model``, ``n_heads``, ``n_kv``, ``head_dim``, ``d_ff``, ``vocab``
-(SwiGLU MLP, GQA attention, untied or tied head).  A multiply-add counts
-two operations.  Padding to shape buckets never counts: every function
-takes the true lengths.
+``d_model``, ``n_heads``, ``n_kv``, ``head_dim``, ``d_ff``, ``vocab``.
+The weights a token multiplies are its family's (``matmul_params`` of
+``bench/families/<family>.py``); attention is GQA's.  A multiply-add
+counts two operations.  Padding to shape buckets never counts: every function takes
+the true lengths.
 """
 from __future__ import annotations
 
+from . import common
+
 
 def matmul_params(m: dict) -> int:
-    """Weights every token multiplies once per forward pass: attention
-    projections and MLP of every layer, and the output head."""
-    d, hd = m["d_model"], m["head_dim"]
-    attn = d * (m["n_heads"] + 2 * m["n_kv"]) * hd + m["n_heads"] * hd * d
-    mlp = 3 * d * m["d_ff"]
-    return m["n_layers"] * (attn + mlp) + d * m["vocab"]
+    """Weights every token multiplies once per forward pass."""
+    return common.family(m).matmul_params(m)
 
 
-def attn_flops(m: dict, context: int) -> float:
+def attn_flops(m: dict, context: float) -> float:
     """Forward attention operations of one query token that attends to
     ``context`` keys (itself included): scores and weighted sum."""
     return 4.0 * m["n_layers"] * m["n_heads"] * m["head_dim"] * context
@@ -29,8 +29,7 @@ def prefill_flops(m: dict, n: int, start: int = 0) -> float:
     that follows ``start`` cached tokens), causal attention included."""
     tokens = n - start
     ctx = (start + 1 + n) * tokens / 2.0       # sum of (i + 1), i in range
-    return 2.0 * matmul_params(m) * tokens + \
-        4.0 * m["n_layers"] * m["n_heads"] * m["head_dim"] * ctx
+    return 2.0 * matmul_params(m) * tokens + attn_flops(m, ctx)
 
 
 def decode_flops(m: dict, context: int) -> float:

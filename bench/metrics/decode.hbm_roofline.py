@@ -1,8 +1,11 @@
-"""The bytes the traced stretch's decode steps had to read, at the chip's
-HBM bandwidth, over the device's busy time in that stretch, in percent.
+"""The bytes the traced stretch's decode steps had to read, at the HBM
+bandwidth of the cell's chips (one chip's times their count), over the
+device's busy time in that stretch (averaged over the chips), in
+percent.
 
 Each decode step must read every weight once; each output token it
-yields must read its row's K and V up to the token's position.  Steps
+yields must read its row's K and V up to the token's position: the bytes
+of the whole model, the sum over the chips that hold it.  Steps
 are the engine's ``decode_steps`` between the trace's start and the
 window's close; tokens are those that arrived between them.  The busy
 time is the device trace's, so a slower or faster host loop does not
@@ -28,5 +31,6 @@ def read(res):
             if j and t0 <= x < t1:
                 kv += flops.kv_bytes(m, p + j)
     need = steps * flops.weight_bytes(m) + kv
-    bw = peaks.peak(res["device"]["kind"])["hbm_bytes_per_s"]
+    dev = res["device"]
+    bw = peaks.peak(dev["kind"])["hbm_bytes_per_s"] * dev["count"]
     return 100.0 * need / bw / tr["busy_s"]

@@ -1,5 +1,6 @@
 """Model FLOPs the window's served work needs, over the window and the
-chip's bf16 peak, in percent.
+bf16 peak of the cell's chips (one chip's times their count), in
+percent.
 
 The work: each request whose first token arrived in the window needed
 its whole prompt's forward pass (causal attention at each position), and
@@ -23,5 +24,6 @@ def read(res):
                 continue
             total += (flops.prefill_flops(m, p) if j == 0
                       else flops.decode_flops(m, p + j))
-    peak = peaks.peak(res["device"]["kind"])["bf16_flops"]
+    dev = res["device"]
+    peak = peaks.peak(dev["kind"])["bf16_flops"] * dev["count"]
     return 100.0 * total / sec / peak if total else None

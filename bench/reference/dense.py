@@ -2,8 +2,9 @@
 pre-norm RMSNorm layers, GQA attention with rotary embeddings on the
 first ``rope_fraction`` of each head (the two halves of that part rotated
 against each other), a SwiGLU MLP, and an output head that may be tied to
-the embedding.  It follows :mod:`bench.weights` for names and imports
-nothing of the program.
+the embedding.  It follows ``bench/families/dense.py`` for names and
+imports nothing of the program.  Weights split over several chips stay
+split: each jitted step runs over their mesh.
 
 Every matrix product runs at ``Precision.HIGHEST``.  ``mode="fp8"`` is
 the control: the operands of every projection are rounded to

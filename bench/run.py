@@ -117,8 +117,12 @@ def report(res: dict):
             f"p99 {common.quantile(late, 0.99)!r} s ({len(late)} sends)")
         log(f"requests sent {len(res['client'].all)} in window "
             f"{n['attempted']} succeeded {n['finished']} failed "
-            f"{n['failed']}; ttft samples {n['n_ttft']} itl gaps "
-            f"{n['n_gaps']} tokens {n['tokens']}")
+            f"{n['failed']}; ttft samples {n['n_ttft']} tokens "
+            f"{n['tokens']}")
+        gaps = n["gaps_ms"]
+        log(f"itl gaps {len(gaps)}: " + " ".join(
+            f"p{q} {common.quantile(gaps, q / 100)!r} ms"
+            for q in (50, 90, 95, 99)))
     else:
         log(f"steps {n['steps']} in {n['elapsed_s']!r} s; losses "
             f"{res['check']['losses']} reference "

@@ -106,31 +106,6 @@ class _no_span:
 # -- set-up ----------------------------------------------------------------
 
 
-def arch_config(cfg: dict, m: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
-    from repro.configs.base import ArchConfig
-    frac = m["rope_fraction"]
-    return ArchConfig(
-        name=cfg["name"], family="dense", n_layers=m["n_layers"],
-        d_model=m["d_model"], n_heads=m["n_heads"], n_kv=m["n_kv"],
-        d_ff=m["d_ff"], vocab=m["vocab"], head_dim=m["head_dim"],
-        rope="full" if frac == 1 else "partial2d",
-        rope_kw=() if frac == 1 else (("fraction", frac),),
-        act="swiglu", tie_embeddings=bool(m["tie_embeddings"]),
-        source=cfg["source"])
-
-
-def check_model(m: dict):
-    """The program computes RMSNorm with eps 1e-5, rotary base 10000 and
-    SwiGLU in bfloat16; a configuration that states otherwise cannot run
-    on it."""
-    want = {"norm_eps": 1e-5, "rope_theta": 10000.0, "act": "silu",
-            "dtype": "bfloat16"}
-    bad = {k: m[k] for k, v in want.items() if m[k] != v}
-    if bad:
-        raise ValueError(f"the program cannot run {bad}; it runs {want}")
-
-
 def warm_waves(eng, mix: dict, serve_cfg: dict) -> list:
     """Waves of (prompt_len, max_new) that together reach every shape the
     mix can: each prefill bucket at each group tier, each chunk length at
@@ -179,19 +154,24 @@ def stagger(rows: int, gap: int = 4) -> list:
     return [2 + gap * k for k in stage]
 
 
-def setup(cfg: dict, mix: dict, seed: int, clock):
+def setup(cfg: dict, mix: dict, seed: int, devices):
+    """The program, its engine warmed up, the weights and the model dict.
+    Over several devices the weights and the program's tree are split
+    over the cell's mesh (:func:`common.cell_mesh`)."""
     import jax
     import jax.numpy as jnp
     from repro import api
     from repro.serve import ServeConfig
 
     m = common.model_dims(cfg)
-    check_model(m)
-    program = api.compile(arch_config(cfg, m))
-    w = weights.make(m, common.seed_key(seed), jnp.bfloat16)
+    fam = common.family(m)
+    fam.check_model(m)
+    mesh = common.cell_mesh(devices)
+    program = api.compile(fam.arch_config(cfg, m), mesh=mesh)
+    w = weights.make(m, common.seed_key(seed), jnp.bfloat16, mesh)
     jax.block_until_ready(w)
     sc = cfg["serve"]
-    eng = program.serve(weights.to_program(w), ServeConfig(
+    eng = program.serve(weights.to_program(w, m, program), ServeConfig(
         max_batch=sc["max_batch"], s_max=m["s_max"],
         prefill_buckets=tuple(sc["prefill_buckets"]),
         prefill_batch=sc["prefill_batch"], greedy=True))
@@ -293,11 +273,10 @@ def window_numbers(d: Client, w0: float, seconds: float, mix: dict) -> dict:
         gaps.extend(b - a for a, b in zip(ts, ts[1:]) if w0 <= a and b < w1)
     failed = sum(1 for t in in_window if t.done and not t.req.ok)
     return {"ttft_p90_s": common.quantile(ttft, 0.9),
-            "itl_p99_ms": 1e3 * common.quantile(gaps, 0.99),
             "serve_output_tokens_per_s": tokens / seconds,
             "attempted": len(in_window), "failed": failed,
             "finished": sum(1 for t in in_window if t.done and t.req.ok),
-            "n_ttft": len(ttft), "n_gaps": len(gaps),
+            "n_ttft": len(ttft), "gaps_ms": [1e3 * g for g in gaps],
             "tokens": tokens}
 
 
@@ -330,7 +309,7 @@ def logit_gaps(w: dict, m: dict, seqs: list, mode: str = "f32",
     memory beside the weights."""
     import jax.numpy as jnp
 
-    from .reference import dense
+    ref = common.reference(m)
     full = [np.concatenate([p, s]).astype(np.int32) for p, s in seqs]
     groups, cur = [], []
     for i, f in enumerate(full):
@@ -346,8 +325,8 @@ def logit_gaps(w: dict, m: dict, seqs: list, mode: str = "f32",
         ids = np.zeros((len(group), P), np.int32)
         for r, j in enumerate(group):
             ids[r, :len(full[j])] = full[j]
-        z_all = dense.logits(w, jnp.asarray(ids), m)
-        zc_all = (dense.logits(w, jnp.asarray(ids), m, mode=mode)
+        z_all = ref.logits(w, jnp.asarray(ids), m)
+        zc_all = (ref.logits(w, jnp.asarray(ids), m, mode=mode)
                   if mode != "f32" else None)
         for r, j in enumerate(group):
             prompt, served = seqs[j]
@@ -395,7 +374,7 @@ def check(d: Client, w, m, seed: int, mix: dict, limit: float,
 
 def run(cfg: dict, mix: dict, seed: int, seconds: float, tracer, clock,
         devices, control: bool = False) -> dict:
-    program, eng, w, m = setup(cfg, mix, seed, clock)
+    program, eng, w, m = setup(cfg, mix, seed, devices)
     plan = traffic.plan_requests(mix, seconds)
     st0 = eng.stats
     c0 = clock.mark()

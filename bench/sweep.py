@@ -37,12 +37,15 @@ def main(argv=None) -> int:
     cfg = common.load_config(bench, cell["config"])
     mix = common.load_traffic(cell["traffic"])
     import jax
-    if jax.devices()[0].platform != "tpu":
-        print("sweep: JAX found no TPU", file=sys.stderr)
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
+        print(f"sweep: {cell['name']} needs {cell['chips']} TPU chips, "
+              f"JAX found {len(devices)} {devices[0].platform} devices",
+              file=sys.stderr)
         return 2
     common.use_compile_cache()
-    clock = common.CompileClock()
-    _, eng, _, m = serve.setup(cfg, mix, args.seed, clock)
+    _, eng, _, m = serve.setup(cfg, mix, args.seed,
+                               devices[:cell["chips"]])
     for rate in (float(r) for r in args.rates.split(",")):
         mx = dict(mix, rate_per_s=rate)
         plan = traffic.plan_requests(mx, args.seconds, drain_s=0)

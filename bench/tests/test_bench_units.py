@@ -122,27 +122,56 @@ def test_idle_share_reader():
     assert read({"trace": None}) is None
 
 
-def test_decode_roofline_reads_the_device_busy_time():
+class _Tok:
+    plan = traffic.Planned(0, 3, 4)
+    tokens = [0.5, 1.5, 2.5, 3.5]     # output tokens 1..3 at 1.5, 2.5, 3.5
+
+
+@pytest.mark.parametrize("count", [1, 4])
+def test_decode_roofline_reads_the_device_busy_time(count):
     """Bytes the traced stretch's decode steps needed, over the device's
-    busy time in it: the host's window does not enter."""
+    busy time in it and the bandwidth of the cell's chips: the host's
+    window does not enter."""
     read = common.load_metric_reader("decode.hbm_roofline")
-
-    class T:
-        plan = traffic.Planned(0, 3, 4)
-        tokens = [0.5, 1.5, 2.5, 3.5]     # output tokens 1..3 at 1.5, 2.5, 3.5
-
     res = {"kind": "serve", "m": M, "t_trace": 1.0, "t_close": 3.0,
            "stats_trace": {"decode_steps": 10},
            "stats1": {"decode_steps": 12},
-           "client": type("C", (), {"all": [T()]}),
-           "device": {"kind": "TPU v5 lite"},
+           "client": type("C", (), {"all": [_Tok()]}),
+           "device": {"kind": "TPU v5 lite", "count": count},
            "trace": {"busy_s": 1e-6, "window_s": 2.0, "n_ops": 5}}
     need = 2 * flops.weight_bytes(M) + flops.kv_bytes(M, 4) \
         + flops.kv_bytes(M, 5)
-    assert read(res) == pytest.approx(100 * need / 819e9 / 1e-6)
+    assert read(res) == pytest.approx(100 * need / 819e9 / 1e-6 / count)
     res["trace"] = {"busy_s": 0.0, "window_s": 2.0, "n_ops": 0}
     assert read(res) is None
     assert read(dict(res, trace=None)) is None
+
+
+@pytest.mark.parametrize("name", ["serve.mfu", "train.mfu",
+                                  "decode.hbm_roofline"])
+def test_roofline_readers_divide_by_the_cells_chips(name):
+    """Each share of a peak is over one chip's peak times the cell's
+    chips: the same work on four chips reads a quarter, and one chip
+    reads what the per-chip formula gives."""
+    read = common.load_metric_reader(name)
+    res = {"kind": "train" if name == "train.mfu" else "serve", "m": M,
+           "mix": {"seq": 8}, "seconds": 4.0, "w0": 0.0,
+           "numbers": {"train_tokens_per_s": 1e6},
+           "t_trace": 1.0, "t_close": 3.0,
+           "stats_trace": {"decode_steps": 10},
+           "stats1": {"decode_steps": 12},
+           "client": type("C", (), {"all": [_Tok()]}),
+           "trace": {"busy_s": 1e-6, "window_s": 2.0, "n_ops": 5}}
+    one, four = (read(dict(res, device={"kind": "TPU v5 lite",
+                                        "count": n})) for n in (1, 4))
+    assert one > 0 and four == pytest.approx(one / 4)
+    if name == "train.mfu":
+        assert one == 100.0 * flops.train_flops_per_token(M, 8) * 1e6 \
+            / 197e12
+    if name == "serve.mfu":
+        work = flops.prefill_flops(M, 3) + sum(
+            flops.decode_flops(M, 3 + j) for j in (1, 2, 3))
+        assert one == pytest.approx(100.0 * work / 4.0 / 197e12)
 
 
 # -- the open loop ------------------------------------------------------------
@@ -227,6 +256,11 @@ def test_every_cell_resolves_its_files():
         cfg = common.load_config(bench, cell["config"])
         m = common.model_dims(cfg)
         assert m["d_model"] % m["n_heads"] == 0
+        fam = common.family(m)
+        fam.check_model(m)
+        assert set(fam.shapes(m)) <= set(fam.PROGRAM_PATHS)
+        assert set(fam.STACKED) | set(fam.SPLIT) <= set(fam.PROGRAM_PATHS)
+        assert common.reference(m).logits
         assert common.load_traffic(cell["traffic"])["kind"]
         e2e = common.cell_metrics(bench, cell["name"], "end_to_end")
         assert "setup_s" in [x["name"] for x in e2e] and len(e2e) >= 2

@@ -24,7 +24,6 @@ import time
 import numpy as np
 
 from . import common, weights
-from .serve import arch_config, check_model
 
 CHECK_STEPS = 3
 
@@ -45,7 +44,7 @@ def _f32_copy(tree):
     return jax.tree_util.tree_map(lambda a: jnp.array(a, jnp.float32), tree)
 
 
-def setup(cfg: dict, mix: dict, seed: int):
+def setup(cfg: dict, mix: dict, seed: int, devices):
     """The step object and the readings of its first three steps."""
     import jax
     import jax.numpy as jnp
@@ -53,10 +52,15 @@ def setup(cfg: dict, mix: dict, seed: int):
     from repro.optim import AdamWConfig
     from repro.train import TrainStepConfig
 
+    if len(devices) > 1:
+        raise NotImplementedError(
+            "a training cell runs on one chip: the readings of the first "
+            "steps are taken from the program's one-chip parameter tree")
     m = common.model_dims(cfg)
-    check_model(m)
+    fam = common.family(m)
+    fam.check_model(m)
     o = mix["optimizer"]
-    program = api.compile(arch_config(cfg, m))
+    program = api.compile(fam.arch_config(cfg, m))
     tcfg = TrainStepConfig(
         optimizer=AdamWConfig(lr=o["lr"], b1=o["b1"], b2=o["b2"],
                               eps=o["eps"], weight_decay=o["weight_decay"],
@@ -67,7 +71,7 @@ def setup(cfg: dict, mix: dict, seed: int):
     key = common.seed_key(seed)
     w = weights.make(m, key, jnp.bfloat16)
     w0 = _f32_copy(w)
-    params = weights.to_program(w)
+    params = weights.to_program(w, m)
     del w
     opt = step.init_opt(params)
     fn = jax.jit(step.fn, donate_argnums=(0, 1))
@@ -81,8 +85,8 @@ def setup(cfg: dict, mix: dict, seed: int):
         losses.append(float(met["loss"]))
         if i == 0:
             m1 = {k: jnp.array(v["m"]) for k, v in
-                  weights.from_program(opt["state"]).items()}
-    p3 = _f32_copy(weights.from_program(params))
+                  weights.from_program(opt["state"], m).items()}
+    p3 = _f32_copy(weights.from_program(params, m))
     jax.block_until_ready((p3, m1))
     state = {"program": program, "fn": fn, "params": params, "opt": opt,
              "batch_fn": batch_fn, "data_key": data_key, "next": CHECK_STEPS}
@@ -138,17 +142,17 @@ def reference(m: dict, mix: dict, readings: dict, data_key, batch_fn,
               mode: str = "f32") -> dict:
     """The reference's three steps from the same weights and batches."""
     import jax
-    from .reference import dense
+    ref = common.reference(m)
     o = mix["optimizer"]
-    mh = dense.hashable(m)
+    mh = ref.hashable(m)
     w = readings["w0"]
     state = None
     losses, g1 = [], None
     for k in range(CHECK_STEPS):
         b = batch_fn(data_key, k)
-        loss, g = dense.loss_and_grads(w, b["ids"], b["labels"], mh,
-                                       mode=mode)
-        w, state, gc_ = dense.adamw_step(w, g, state, k, o)
+        loss, g = ref.loss_and_grads(w, b["ids"], b["labels"], mh,
+                                     mode=mode)
+        w, state, gc_ = ref.adamw_step(w, g, state, k, o)
         losses.append(float(loss))
         if k == 0:
             g1 = jax.device_get(gc_)
@@ -193,7 +197,7 @@ def compare(readings: dict, ref: dict, b1: float, limits: dict) -> dict:
 
 def run(cfg: dict, mix: dict, seed: int, seconds: float, tracer, clock,
         devices, control: bool = False) -> dict:
-    st, readings, m = setup(cfg, mix, seed)
+    st, readings, m = setup(cfg, mix, seed, devices)
     c0 = clock.mark()
     tps = mix["batch"] * mix["seq"]
     w0_t = time.perf_counter()

@@ -1,76 +1,61 @@
-"""Weights of a dense decoder, made on the device from the seed, and
-where the program under test keeps each of them.
+"""Weights of a model, made on the device from the seed, and where the
+program under test keeps each of them.
 
 The benchmark makes the weights, not the program: the reference reads
 the same arrays under its own names, and so takes nothing the program
-made.  Names (``m`` is :func:`common.model_dims`)::
+made.  The family module (:func:`common.family`) gives each canonical
+name's shape and init, the names stacked by layer, the axis each matrix
+is split on over several chips, and the path of each in the program's
+tree.
 
-    embed   (vocab, d)                 normal, std 0.02
-    ln1     (L, d), ln2 (L, d)          ones
-    wqkv    (L, d, (H + 2 K) hd)        q heads, then K keys, then K values
-    wo      (L, H hd, d)
-    w_in    (L, d, 2 F)                 gate, then up (SwiGLU)
-    w_out   (L, F, d)
-    ln_f    (d,)                        ones
-    head    (d, vocab)                  absent where the embedding is tied
-
-Matrices are normal with std ``1 / sqrt(fan_in)``, drawn in float32 and
-stored in the served type, one layer at a time inside one jitted call.
+Matrices are drawn in float32 and stored in the served type, one layer at
+a time inside one jitted call.  Over several chips they are drawn
+straight into their shards: with the threefry generator partitionable
+(JAX's default), each shard draws its own part of the same numbers, so
+the weights are bitwise those of one chip and no chip holds more than its
+share and one layer's temporaries.
 """
 from __future__ import annotations
 
 import functools
 import zlib
 
-# canonical name -> path in the program's parameter tree
-PROGRAM_PATHS = {
-    "embed": ("embed", "emb", "w"),
-    "ln1": ("layers", "ln1", "g"),
-    "wqkv": ("layers", "qkv", "proj", "lin", "w"),
-    "wo": ("layers", "oproj", "proj", "lin", "w"),
-    "ln2": ("layers", "ln2", "g"),
-    "w_in": ("layers", "mlp", "wi", "lin", "w"),
-    "w_out": ("layers", "mlp", "wo", "lin", "w"),
-    "ln_f": ("head", "ln", "g"),
-    "head": ("head", "out", "w"),
-}
-STACKED = ("ln1", "wqkv", "wo", "ln2", "w_in", "w_out")
+from . import common
 
 
-def shapes(m: dict) -> dict:
-    """Canonical name -> (shape, init) where init is ``"ones"`` or the
-    normal's standard deviation."""
-    d, hd, L = m["d_model"], m["head_dim"], m["n_layers"]
-    H, K, F, V = m["n_heads"], m["n_kv"], m["d_ff"], m["vocab"]
-    out = {
-        "embed": ((V, d), 0.02),
-        "ln1": ((L, d), "ones"),
-        "wqkv": ((L, d, (H + 2 * K) * hd), d ** -0.5),
-        "wo": ((L, H * hd, d), (H * hd) ** -0.5),
-        "ln2": ((L, d), "ones"),
-        "w_in": ((L, d, 2 * F), d ** -0.5),
-        "w_out": ((L, F, d), F ** -0.5),
-        "ln_f": ((d,), "ones"),
-    }
-    if not m["tie_embeddings"]:
-        out["head"] = ((d, V), d ** -0.5)
+def shardings(m: dict, mesh) -> dict:
+    """Canonical name -> ``NamedSharding`` over ``mesh``: each matrix in
+    the family's ``SPLIT`` split on that axis over ``model``, the rest
+    replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    fam = common.family(m)
+    out = {}
+    for name, (shape, _) in fam.shapes(m).items():
+        dims = [None] * len(shape)
+        if name in fam.SPLIT:
+            dims[fam.SPLIT[name]] = "model"
+        out[name] = NamedSharding(mesh, P(*dims))
     return out
 
 
-def make(m: dict, key, dtype):
-    """All weights, on the default device, from one key, in ``dtype``."""
+def make(m: dict, key, dtype, mesh=None):
+    """All weights from one key, in ``dtype``: on the default device, or
+    split over ``mesh`` (:func:`shardings`)."""
     import jax
-    return jax.jit(functools.partial(_make, m, dtype=dtype))(key)
+    kw = {} if mesh is None else {"out_shardings": shardings(m, mesh)}
+    return jax.jit(functools.partial(_make, m, dtype=dtype), **kw)(key)
 
 
 def _make(m, key, dtype):
     import jax
     import jax.numpy as jnp
     from jax import lax
+    fam = common.family(m)
     out = {}
-    for name, (shape, init) in shapes(m).items():
-        if init == "ones":
-            out[name] = jnp.ones(shape, dtype)
+    for name, (shape, init) in fam.shapes(m).items():
+        if init in ("ones", "zeros"):
+            out[name] = (jnp.ones if init == "ones" else jnp.zeros)(
+                shape, dtype)
             continue
         k = jax.random.fold_in(key, zlib.crc32(name.encode()))
 
@@ -78,7 +63,7 @@ def _make(m, key, dtype):
             return (jax.random.normal(k, shape, jnp.float32)
                     * init).astype(dtype)
 
-        if name in STACKED:
+        if name in fam.STACKED:
             ks = jax.vmap(lambda i, k=k: jax.random.fold_in(k, i))(
                 jnp.arange(shape[0]))
             out[name] = lax.map(functools.partial(one, shape=shape[1:]), ks)
@@ -87,23 +72,63 @@ def _make(m, key, dtype):
     return out
 
 
-def to_program(w: dict) -> dict:
-    """The program's nested tree over the same arrays (no copy)."""
+def to_program(w: dict, m: dict, program=None) -> dict:
+    """The program's nested tree over the same arrays.  On one chip no
+    array is copied.  Where ``program`` runs over a mesh, the weights
+    are laid out as its tensor-parallel tree (the family's
+    ``program_columns``) and placed with the program's own shardings,
+    one layer at a time."""
+    fam = common.family(m)
+    if program is not None and program.mesh is not None:
+        w = _relayout(w, m, program)
     tree: dict = {}
     for name, arr in w.items():
+        path = fam.PROGRAM_PATHS[name]
         node = tree
-        path = PROGRAM_PATHS[name]
         for p in path[:-1]:
             node = node.setdefault(p, {})
         node[path[-1]] = arr
     return tree
 
 
-def from_program(tree: dict) -> dict:
+def program_shardings(program) -> dict:
+    """The program's parameter shardings, as its prefill step takes
+    them."""
+    tp = dict(zip(program.mesh.axis_names,
+                  program.mesh.devices.shape))["model"]
+    return program.prefill(1, 8 * tp).in_shardings[0]
+
+
+def _relayout(w: dict, m: dict, program) -> dict:
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    fam = common.family(m)
+    cols = fam.program_columns(program.model, m)
+    shd = program_shardings(program)
+    out_shd = {}
+    for name in w:
+        node = shd
+        for p in fam.PROGRAM_PATHS[name]:
+            node = node[p]
+        out_shd[name] = node
+
+    def move(w):
+        out = dict(w)
+        for name, idx in cols.items():       # layer weights, one at a time
+            idx = jnp.asarray(idx)
+            out[name] = lax.map(lambda x, idx=idx: jnp.take(x, idx, axis=-1),
+                                w[name])
+        return out
+
+    return jax.jit(move, out_shardings=out_shd)(w)
+
+
+def from_program(tree: dict, m: dict) -> dict:
     """Canonical names over a tree shaped like the program's (its
     parameters, or optimizer moments that mirror them)."""
     out = {}
-    for name, path in PROGRAM_PATHS.items():
+    for name, path in common.family(m).PROGRAM_PATHS.items():
         node = tree
         for p in path:
             if not isinstance(node, dict) or p not in node:
