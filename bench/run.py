@@ -132,6 +132,11 @@ def report(res: dict):
     peak = res["peak_bytes"]
     log(f"peak HBM {peak!r} bytes"
         + (f" ({peak / 2**30:.2f} GiB)" if peak else ""))
+    tr = res["trace"]
+    if tr:
+        log(f"trace programs {tr['modules']}; collectives "
+            f"{tr['collective_s']!r} s, exposed "
+            f"{tr['exposed_collective_s']!r} s")
     log(f"set-up {res['setup_s']:.2f} s: {count} compiles "
         f"({secs:.1f} s compiling), {hits} persistent-cache hits; "
         f"window compiles {res['compiles_window']}; cache {res['cache_dir']}")

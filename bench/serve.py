@@ -272,11 +272,13 @@ def window_numbers(d: Client, w0: float, seconds: float, mix: dict) -> dict:
         tokens += sum(1 for x in ts if w0 <= x < w1)
         gaps.extend(b - a for a, b in zip(ts, ts[1:]) if w0 <= a and b < w1)
     failed = sum(1 for t in in_window if t.done and not t.req.ok)
+    gaps_ms = [1e3 * g for g in gaps]
     return {"ttft_p90_s": common.quantile(ttft, 0.9),
+            "itl_p90_ms": common.quantile(gaps_ms, 0.9),
             "serve_output_tokens_per_s": tokens / seconds,
             "attempted": len(in_window), "failed": failed,
             "finished": sum(1 for t in in_window if t.done and t.req.ok),
-            "n_ttft": len(ttft), "gaps_ms": [1e3 * g for g in gaps],
+            "n_ttft": len(ttft), "gaps_ms": gaps_ms,
             "tokens": tokens}
 
 

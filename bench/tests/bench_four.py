@@ -9,7 +9,9 @@ one device; the program's tree laid out over the mesh is bitwise what the
 program's own host relayout (``dense_tp_params``) gives, with the
 program's shardings; the reference over the mesh gives the one-device
 reference's logits within float32 rounding.  ``serve`` and ``train``: a
-4-chip set-up, driven as a run drives it, past the device check.
+4-chip run of a tiny cell, driven as ``bench/run.py`` drives it past the
+device check; one that completes prints its result line last, as
+``bench/run.py`` does.
 """
 from __future__ import annotations
 
@@ -77,7 +79,9 @@ def weights_case():
     assert rel <= REL_TOL and gap <= REL_TOL * float(np.abs(z1).max())
 
 
-def setup_case(name: str):
+def run_case(name: str):
+    import json
+
     import jax
     from bench_tiny import cell
 
@@ -85,15 +89,16 @@ def setup_case(name: str):
     from bench import run as bench_run
     common.use_compile_cache = lambda: "none"
     bench, c, cfg, mix = cell(name)
-    bench_run.run_cell(bench, c, cfg, mix, 5, 1.0, False,
-                       jax.devices()[:4], time.perf_counter())
+    out = bench_run.run_cell(bench, c, cfg, mix, 5, 1.0, False,
+                             jax.devices()[:4], time.perf_counter())
+    print(json.dumps(out), flush=True)
 
 
 if __name__ == "__main__":
     case = sys.argv[1]
     if case == "weights":
         weights_case()
+        print(f"{case}: done")
     else:
-        setup_case({"serve": "chatglm3-6b.chat",
-                    "train": "smollm-135m.train"}[case])
-    print(f"{case}: done")
+        run_case({"serve": "chatglm3-6b.chat",
+                  "train": "smollm-135m.train"}[case])

@@ -16,7 +16,8 @@ def test_chat_cell_is_correct(monkeypatch):
     assert out["correct"], out["checks"]
     assert out["failed"] == 0 and out["attempted"] >= 10
     m = out["metrics"]
-    assert set(m) == {"ttft_p90_s", "serve_output_tokens_per_s", "setup_s"}
+    assert set(m) == {"ttft_p90_s", "itl_p90_ms", "serve_output_tokens_per_s",
+                      "setup_s"}
     assert all(v["value"] > 0 for v in m.values())
     assert out["device"]["platform"] == "cpu"
     assert list(out)[-1] == "checks"

@@ -98,19 +98,65 @@ def test_flops_by_hand():
 # -- trace -------------------------------------------------------------------
 
 
-def test_trace_reduction_of_a_small_recorded_trace():
+def _recorded(name: str):
     from jax.profiler import ProfileData
-    text = (Path(__file__).parent / "data" / "small_trace.pbtxt").read_text()
-    pd = ProfileData.from_text_proto(text)
-    r = trace.reduce(pd)
-    assert r["window_s"] == pytest.approx(10e-6)
-    assert r["busy_s"] == pytest.approx(4e-6)     # overlap counted once
-    assert r["n_ops"] == 3                        # the last is outside
-    assert r["device_ops"] == [["fusion.1", pytest.approx(3.5e-6)],
-                               ["_fused_add_rmsnorm_kernel",
-                                pytest.approx(1e-6)]]
-    assert r["idle_gaps"] == [["generator", pytest.approx(5e-6)],
-                              ["engine.step", pytest.approx(1e-6)]]
+    return ProfileData.from_text_proto(
+        (Path(__file__).parent / "data" / name).read_text())
+
+
+def test_trace_reduction_of_a_small_recorded_trace():
+    """Overlap counted once, the last operation outside the window, gaps
+    named by the host span over them.  These keys, which every per-layer
+    reading rests on, hold their exact values; a trace with no programs
+    and no collectives reads none."""
+    r = trace.reduce(_recorded("small_trace.pbtxt"))
+    assert r == {"busy_s": 4e-6, "window_s": 1e-5,
+                 "device_ops": [["fusion.1", 3.5e-6],
+                                ["_fused_add_rmsnorm_kernel", 1e-6]],
+                 "idle_gaps": [["generator", 5e-6], ["engine.step", 1e-6]],
+                 "n_ops": 3, "modules": {}, "collective_s": 0.0,
+                 "exposed_collective_s": 0.0}
+
+
+def test_collective_and_program_time_of_a_two_chip_trace():
+    """By hand from the comment at the top of ``collective_trace.pbtxt``:
+    chip 0 has 7 us of collectives (a synchronous all-reduce, and an async
+    pair in flight from its start to its done), 3.5 us of it exposed;
+    chip 1 has 3 us (an all-reduce wrapped in a fusion), 2 us exposed,
+    under a loop that holds it and hides nothing.  Each is averaged over
+    the two chips; a program the window's end cuts is left out."""
+    r = trace.reduce(_recorded("collective_trace.pbtxt"))
+    assert r["collective_s"] == pytest.approx(5e-6, abs=1e-15)
+    assert r["exposed_collective_s"] == pytest.approx(2.75e-6, abs=1e-15)
+    assert r["modules"] == {
+        "jit_decode_t16": {"seconds": pytest.approx(8e-6), "count": 1.0},
+        "jit_decode_t8": {"seconds": pytest.approx(2.5e-6), "count": 0.5}}
+    assert r["busy_s"] == pytest.approx(10.5e-6)
+    assert r["window_s"] == pytest.approx(20e-6)
+    assert r["n_ops"] == 10
+
+
+@pytest.mark.parametrize("hlo,kind", [
+    ("%all-reduce.3 = f32[8]{0} all-reduce(%x), replica_groups={}",
+     ("all-reduce", "")),
+    ("%ar.1 = bf16[4,4096]{1,0} all-reduce-start(%y)",
+     ("all-reduce", "start")),
+    ("%ar.2 = bf16[4,4096]{1,0} all-reduce-done(%ar.1)",
+     ("all-reduce", "done")),
+    ("%all-reduce-fusion.2 = bf16[4]{0} fusion(%y), kind=kOutput",
+     ("all-reduce", "")),
+    ("%rs = bf16[2]{0} reduce-scatter(%z), dimensions={0}",
+     ("reduce-scatter", "")),
+    ("%ag.4 = bf16[8]{0} all-gather(%z), dimensions={0}", ("all-gather", "")),
+    ("%a2a = bf16[8]{0} all-to-all(%z)", ("all-to-all", "")),
+    ("%cp-start = (bf16[8]{0}, bf16[8]{0}) collective-permute-start(%z)",
+     ("collective-permute", "start")),
+    ("all-gather-done.7", ("all-gather", "done")),
+    ("%fusion.3 = bf16[8,4096]{1,0:T(8,128)} fusion(%all-reduce.1)", None),
+    ("%reduce.5 = f32[8]{0} reduce(%a, %b), dimensions={1}", None),
+    ("_fused_add_rmsnorm_kernel", None)])
+def test_collectives_are_told_by_opcode_or_wrapping_name(hlo, kind):
+    assert trace.collective(hlo) == kind
 
 
 def test_idle_share_reader():
@@ -120,6 +166,22 @@ def test_idle_share_reader():
     assert read({"trace": {"busy_s": 0.0, "window_s": 10.0,
                            "n_ops": 0}}) is None
     assert read({"trace": None}) is None
+
+
+def test_decode_step_reader():
+    """Device seconds of every decode tier's program over its runs; None
+    where no decode program ran in the traced stretch."""
+    read = common.load_metric_reader("device.decode_step_ms")
+    mods = {"jit_decode_t16": {"seconds": 0.24, "count": 10.0},
+            "jit_decode_t8": {"seconds": 0.04, "count": 2.0},
+            "jit_prefill_b1_s1024": {"seconds": 0.5, "count": 1.0}}
+    assert read({"trace": {"modules": mods}}) == pytest.approx(0.28 / 12 * 1e3)
+    assert read({"trace": {"modules": {
+        "jit_prefill_b1_s1024": mods["jit_prefill_b1_s1024"]}}}) is None
+    assert read({"trace": {"modules": {}}}) is None
+    assert read({"trace": None}) is None
+    r = trace.reduce(_recorded("collective_trace.pbtxt"))
+    assert read({"trace": r}) == pytest.approx(1e3 * 10.5e-6 / 1.5)
 
 
 class _Tok:

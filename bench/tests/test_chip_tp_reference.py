@@ -4,6 +4,7 @@ served.  It needs a host with four TPU chips and is skipped elsewhere.
 
     python3 bench/tests/test_chip_tp_reference.py sample --out S.npz
     python3 bench/tests/test_chip_tp_reference.py compare --sample S.npz
+    python3 bench/tests/test_chip_tp_reference.py trace
 
 ``sample`` (one chip) runs ``chatglm3-6b.chat`` as ``bench/run.py`` does
 and keeps the requests its check samples.  ``compare`` (four chips, a
@@ -15,6 +16,14 @@ difference of the two references' ``logit_gap`` per served token, each
 chip's peak bytes and the share it is held to: a quarter of the weights,
 plus one layer's matrices in float32 and one block of float32 logits (the
 reference's temporaries).
+
+``trace`` (four chips) runs the program's own 4-way tensor-parallel
+prefill and decode steps, as the mesh-global builders give them
+(``Program.prefill``, ``Program.decode_tiers``) and ``chip_smoke.py``
+drives them, on the harness's weights, and reduces a profiler trace of
+a run of decode steps, then of prefills, with ``bench/trace.py``: the
+operations it counts as collectives, ``collective_s``,
+``exposed_collective_s`` and the device programs.
 """
 from __future__ import annotations
 
@@ -107,6 +116,93 @@ def compare(path: str, chips: int = 4) -> dict:
             "make_s": made_s, "reference_s": ref_s}
 
 
+def traced(fn) -> dict:
+    """``fn()`` under the profiler, inside a ``window`` span, reduced by
+    :func:`bench.trace.reduce`, with the device seconds of each operation
+    that it counts as a collective (averaged over the chips)."""
+    import glob
+    import shutil
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from bench import trace
+    d = tempfile.mkdtemp(prefix="tp_trace_")
+    try:
+        jax.profiler.start_trace(d)
+        with jax.profiler.TraceAnnotation("window"):
+            jax.block_until_ready(fn())
+        jax.profiler.stop_trace()
+        pd = ProfileData.from_file(
+            glob.glob(f"{d}/**/*.xplane.pb", recursive=True)[0])
+        out = trace.reduce(pd)
+        planes = [p for p in trace.device_planes(pd) if trace.op_events(p)]
+        names = {}
+        for p in planes:
+            for n, a, b in trace.op_events(p):
+                if trace.collective(n):
+                    k = trace.short_name(n)
+                    names[k] = names.get(k, 0.0) + (b - a) / 1e9 / len(planes)
+        out["collective_ops"] = sorted(names.items(), key=lambda kv: -kv[1])
+        return out
+    finally:
+        shutil.rmtree(d, ignore_errors=True)
+
+
+def trace_steps(seed: int, chips: int = 4, batch: int = 4, seq: int = 1024,
+                steps: int = 32, cfg: dict | None = None) -> dict:
+    """The program's TP decode steps (``batch`` rows at position ``seq``)
+    and prefills (``batch`` x ``seq``), traced and reduced."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro import api
+
+    from bench import common, weights
+    if cfg is None:
+        bench = common.load_benchmark()
+        cfg = common.load_config(bench,
+                                 common.find_cell(bench, CELL)["config"])
+    m = common.model_dims(cfg)
+    mesh = common.cell_mesh(jax.devices()[:chips])
+    program = api.compile(common.family(m).arch_config(cfg, m), mesh=mesh)
+    w = weights.make(m, common.seed_key(seed), jnp.bfloat16, mesh)
+    params = weights.to_program(w, m, program)
+    del w
+    s_max = m["s_max"]
+    pre = program.prefill(batch, seq, s_max=s_max)
+    dec = program.decode_tiers(batch, s_max, tiers=(batch,))[batch]
+    run_pre = jax.jit(lambda p, b: pre.fn(p, b),
+                      in_shardings=pre.in_shardings)
+    run_dec = jax.jit(lambda p, b: dec.fn(p, b),
+                      in_shardings=dec.in_shardings)
+    rng = np.random.default_rng(seed % 2**32)
+    ids = jnp.asarray(rng.integers(0, m["vocab"], (batch, seq), np.int32))
+    pos = jnp.broadcast_to(jnp.arange(seq, dtype=jnp.int32), (batch, seq))
+    pre_in = {"ids": ids, "positions": pos}
+    out = run_pre(params, pre_in)
+    pad = ((0, 0), (0, 0), (0, s_max - seq), (0, 0), (0, 0))
+    clen = jnp.full((batch,), seq, jnp.int32)
+    dec_in = {"ids": jnp.argmax(out["logits"][:, -1], -1)
+              .astype(jnp.int32)[:, None],
+              "cache_len": clen, "positions": clen[:, None],
+              "k_cache": jnp.pad(out["layers.k"], pad),
+              "v_cache": jnp.pad(out["layers.v"], pad)}
+    del out
+    jax.block_until_ready(run_dec(params, dec_in))
+
+    def decode():
+        return [run_dec(params, dec_in)["logits"] for _ in range(steps)]
+
+    def prefill():
+        return [run_pre(params, pre_in)["logits"] for _ in range(2)]
+
+    return {"seed": seed, "chips": chips, "batch": batch, "seq": seq,
+            "decode_steps": steps, "decode": traced(decode),
+            "prefill": traced(prefill)}
+
+
 def _tpu_chips() -> int:
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
         return 0
@@ -134,7 +230,7 @@ def test_four_chip_reference_agrees_with_one_chip(tmp_path):
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("phase", choices=("sample", "compare"))
+    ap.add_argument("phase", choices=("sample", "compare", "trace"))
     ap.add_argument("--seed", type=int, default=2**31 + 15)
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--out")
@@ -145,6 +241,8 @@ def main(argv=None) -> int:
     if args.phase == "sample":
         seconds = args.seconds or common.load_benchmark()["run_seconds"]
         got = sample(args.seed, seconds, args.out)
+    elif args.phase == "trace":
+        got = trace_steps(args.seed)
     else:
         got = compare(args.sample)
     print(json.dumps(got), flush=True)

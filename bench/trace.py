@@ -1,6 +1,7 @@
 """The traced run: host spans, the profiler's trace of the last stretch
-of the window, and its reduction to device busy time, top operations and
-idle gaps named by what the host was doing.
+of the window, and its reduction to device busy time, top operations,
+idle gaps named by what the host was doing, device time per program and
+collective time.
 
 The harness's own spans (``generator``, ``engine.step``, ``train.step``,
 ``stats``) are ``jax.profiler.TraceAnnotation``s, so they land in the
@@ -11,6 +12,7 @@ from __future__ import annotations
 
 import contextlib
 import glob
+import re
 import shutil
 import tempfile
 import time
@@ -119,13 +121,12 @@ def device_planes(pd):
 CONTAINERS = ("while", "conditional", "call")
 
 
-def short_name(hlo: str) -> str:
-    """``%fusion.3 = bf16[8,4096]{1,0:T(8,128)} fusion(...)`` ->
-    ``fusion.3 bf16[8,4096] fusion``: the instruction, its output shape
-    without layout (``tuple`` for several) and its opcode."""
+def _parts(hlo: str):
+    """(instruction, output shape without layout, opcode) of an
+    operation's HLO text, or None where the name is not HLO text."""
     name, _, rest = hlo.partition(" = ")
     if not rest:
-        return hlo[:120]
+        return None
     if rest.startswith("("):
         depth = 0
         for i, ch in enumerate(rest):
@@ -138,15 +139,84 @@ def short_name(hlo: str) -> str:
         shape = head[0].split("{", 1)[0]
         rest = head[1] if len(head) > 1 else ""
     op = rest.strip().split("(", 1)[0].strip()
-    return f"{name.lstrip('%')} {shape} {op}"[:120]
+    return name.lstrip("%"), shape, op
 
 
-def op_events(plane) -> list:
-    """(name, start_ns, end_ns) of the operations on a device plane: the
-    line named ``XLA Ops``."""
+def short_name(hlo: str) -> str:
+    """``%fusion.3 = bf16[8,4096]{1,0:T(8,128)} fusion(...)`` ->
+    ``fusion.3 bf16[8,4096] fusion``: the instruction, its output shape
+    without layout (``tuple`` for several) and its opcode."""
+    parts = _parts(hlo)
+    if parts is None:
+        return hlo[:120]
+    return " ".join(parts)[:120]
+
+
+COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
+               "collective-permute")
+
+
+def collective(hlo: str):
+    """(kind, phase) of a collective operation, phase ``start``, ``done``
+    or ``""`` (synchronous), or None for any other operation.  Read from
+    the opcode, and from the instruction's name where XLA wraps a
+    collective in a fusion (``all-reduce-fusion.2``)."""
+    parts = _parts(hlo)
+    name, op = (parts[0], parts[2]) if parts else (hlo, "")
+    base = re.sub(r"\.\d+$", "", name)
+    for kind in COLLECTIVES:
+        for word in (op, base):
+            if kind in word:
+                phase = word.rsplit("-", 1)[-1]
+                return kind, phase if phase in ("start", "done") else ""
+    return None
+
+
+def _operand(hlo: str) -> str:
+    """The first operand of an operation's HLO text (``%name``, with or
+    without its shape before it), without ``%``; "" where none is named."""
+    parts = _parts(hlo)
+    got = parts and re.search(r"%([\w.\-]+)",
+                              hlo.partition(f" {parts[2]}(")[2])
+    return got.group(1) if got else ""
+
+
+def collective_intervals(inside) -> tuple:
+    """(collective intervals, other operations' intervals) of one plane's
+    operations (name, start, end).  An async ``-start`` and the ``-done``
+    that waits for it count as one interval from the start's beginning to
+    the done's end: the collective is in flight all that time.  A done
+    pairs with the start it names, else with the earliest open start of
+    its kind; one whose partner lies outside the window counts alone.
+    Loops and calls, which hold other operations, count as neither."""
+    coll, other, open_ = [], [], {}
+    for n, a, b in sorted(inside, key=lambda e: e[1]):
+        c = collective(n)
+        if c is None:
+            if not short_name(n).split(" ")[-1].startswith(CONTAINERS):
+                other.append((a, b))
+        elif c[1] == "start":
+            parts = _parts(n)
+            open_[(parts[0] if parts else n)] = (c[0], a, b)
+        elif c[1] == "done":
+            key = _operand(n)
+            if key not in open_:
+                key = next((k for k, v in open_.items() if v[0] == c[0]),
+                           None)
+            start = open_.pop(key, None) if key is not None else None
+            coll.append((start[1] if start else a, b))
+        else:
+            coll.append((a, b))
+    coll.extend((a, b) for _, a, b in open_.values())
+    return coll, other
+
+
+def op_events(plane, line_name: str = "XLA Ops") -> list:
+    """(name, start_ns, end_ns) of the events on a device plane's line:
+    the operations (``XLA Ops``) or the programs (``XLA Modules``)."""
     out = []
     for line in plane.lines:
-        if line.name == "XLA Ops":
+        if line.name == line_name:
             for e in line.events:
                 out.append((e.name, e.start_ns, e.start_ns + e.duration_ns))
     return out
@@ -165,21 +235,33 @@ def host_spans(pd) -> list:
     return out
 
 
+def module_key(name: str) -> str:
+    """A program's name without the id XLA appends
+    (``jit_decode_t16(42)``, ``jit_decode_t16.3`` -> ``jit_decode_t16``)."""
+    return re.sub(r"(\(\d+\)|\.\d+)$", "", name)
+
+
 def reduce(pd) -> dict:
     """Busy and window seconds, top operations and the longest idle gaps,
-    each named by the host span that covers most of it.  Device times are
-    averaged over the device planes that ran anything."""
+    each named by the host span that covers most of it; per device
+    program, the seconds and count of its executions that began and ended
+    in the window (``modules``); the union of the collectives' intervals
+    (``collective_s``) and the part of it in which no other operation ran
+    (``exposed_collective_s``).  Device times and counts are averaged
+    over the device planes that ran anything."""
     spans = host_spans(pd)
     wins = [(a, b) for n, a, b in spans if n == "window"]
     planes = [(p, op_events(p)) for p in device_planes(pd)]
     planes = [(p, ev) for p, ev in planes if ev]
     if not wins or not planes:
         return {"busy_s": 0.0, "window_s": 0.0, "device_ops": [],
-                "idle_gaps": [], "n_ops": 0}
+                "idle_gaps": [], "n_ops": 0, "modules": {},
+                "collective_s": 0.0, "exposed_collective_s": 0.0}
     lo, hi = wins[0]
     busy, totals, gaps = [], {}, []
     n_ops = 0
-    for _, ev in planes:
+    modules, coll_s, exposed_s = {}, 0, 0
+    for p, ev in planes:
         inside = [(n, max(a, lo), min(b, hi)) for n, a, b in ev
                   if b > lo and a < hi]
         n_ops += len(inside)
@@ -189,6 +271,15 @@ def reduce(pd) -> dict:
             if not short.split(" ")[-1].startswith(CONTAINERS):
                 totals[short] = totals.get(short, 0) + (b - a)
         gaps.extend(_gaps([(a, b) for _, a, b in inside], lo, hi))
+        coll, other = collective_intervals(inside)
+        coll_s += _union(coll)
+        exposed_s += _union(coll + other) - _union(other)
+        for n, a, b in op_events(p, "XLA Modules"):
+            if lo <= a and b <= hi:
+                mod = modules.setdefault(module_key(n),
+                                         {"seconds": 0, "count": 0})
+                mod["seconds"] += b - a
+                mod["count"] += 1
     k = len(planes)
     named = []
     for a, b in sorted(gaps, key=lambda g: g[0] - g[1])[:10]:
@@ -203,4 +294,9 @@ def reduce(pd) -> dict:
     top = sorted(totals.items(), key=lambda kv: -kv[1])[:10]
     return {"busy_s": sum(busy) / k / 1e9, "window_s": (hi - lo) / 1e9,
             "device_ops": [[n, s / k / 1e9] for n, s in top],
-            "idle_gaps": named, "n_ops": n_ops}
+            "idle_gaps": named, "n_ops": n_ops,
+            "modules": {n: {"seconds": v["seconds"] / k / 1e9,
+                            "count": v["count"] / k}
+                        for n, v in sorted(modules.items())},
+            "collective_s": coll_s / k / 1e9,
+            "exposed_collective_s": exposed_s / k / 1e9}
